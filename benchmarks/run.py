@@ -13,6 +13,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig4_aggregate_intensity,
         fig5_layer_intensity,

@@ -90,6 +90,7 @@ import numpy as np
 from repro.configs import get_config, scaled_down
 from repro.core import ABFTConfig, FixedPolicy, Scheme, compute_bound_ai
 from repro.core.hardware import HardwareSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.obs import EngineTelemetry
 from repro.serve.engine import EngineStats, Request, ServeEngine
@@ -480,6 +481,7 @@ def _spec_sweep(model, params, args) -> dict:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--n-layers", type=int, default=2)

@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import jax
+from jax.extend import core as jax_core
 
 FLOP_PRIMITIVES = ("dot_general", "conv_general_dilated")
 
@@ -76,9 +76,9 @@ def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jax_core.ClosedJaxpr):
                 subs.append(item.jaxpr)
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jax_core.Jaxpr):
                 subs.append(item)
     return subs
 

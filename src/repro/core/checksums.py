@@ -20,9 +20,14 @@ from __future__ import annotations
 import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+# Check einsums run at full f32 precision: on a TPU a default-precision f32
+# dot rounds its operands to bf16 (one MXU pass), an error ~2^-9 of the
+# bound that the f32 tolerance below does not allow for.
+CHECK_PRECISION = jax.lax.Precision.HIGHEST
 
 # Empirical safety factor over the sqrt-growth rounding model; calibrated by
 # tests/test_checksums.py (hypothesis sweep: zero false positives at 8x the
@@ -117,8 +122,10 @@ def global_row_check(
     """
     k = x.shape[-1]
     xf = x.astype(F32)
-    check = jnp.einsum("...mk,k->...m", xf, w_sum)
-    bound = jnp.einsum("...mk,k->...m", jnp.abs(xf), w_abs_sum)
+    check = jnp.einsum("...mk,k->...m", xf, w_sum,
+                       precision=CHECK_PRECISION)
+    bound = jnp.einsum("...mk,k->...m", jnp.abs(xf), w_abs_sum,
+                       precision=CHECK_PRECISION)
     yf = y.astype(F32)
     y_rowsum = jnp.sum(yf, axis=-1)
     residual = jnp.abs(check - y_rowsum)
@@ -143,8 +150,10 @@ def global_scalar_check(
     xf = x.astype(F32)
     a_sum = jnp.sum(xf, axis=-2)
     a_abs = jnp.sum(jnp.abs(xf), axis=-2)
-    check = jnp.einsum("...k,k->...", a_sum, w_sum)
-    bound = jnp.einsum("...k,k->...", a_abs, w_abs_sum)
+    check = jnp.einsum("...k,k->...", a_sum, w_sum,
+                       precision=CHECK_PRECISION)
+    bound = jnp.einsum("...k,k->...", a_abs, w_abs_sum,
+                       precision=CHECK_PRECISION)
     yf = y.astype(F32)
     total = jnp.sum(yf, axis=(-1, -2))
     residual = jnp.abs(check - total)

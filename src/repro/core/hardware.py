@@ -11,6 +11,9 @@ block-level ABFT checksum math executes (see DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
+import functools
+
+import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,7 +78,36 @@ NVIDIA_T4 = HardwareSpec(
     fixed_op_overhead_s=5e-6,
 )
 
+# The modelled target of analytical tools and CPU runs (tests, rehearsals):
+# selection there is made for a v5e.  Code that runs on a chip takes its
+# spec from ``device_hardware()`` instead.
 DEFAULT = TPU_V5E
+
+# ``jax.devices()[0].device_kind`` -> spec.  "TPU v5 lite" is what a v5e
+# chip reports.  A TPU kind missing here is an error, never a default.
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: Pallas kernels then run
+    compiled and serving runs in bfloat16."""
+    return jax.default_backend() == "tpu"
+
+
+@functools.cache
+def device_hardware() -> HardwareSpec:
+    """The spec of the chip this process runs on, keyed by its
+    ``device_kind``.  Off a TPU (CPU tests and rehearsals) the modelled
+    target ``TPU_V5E`` is returned, by name."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    try:
+        return BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for TPU device_kind {dev.device_kind!r}; "
+            f"known: {sorted(BY_DEVICE_KIND)}") from None
 
 
 def get_hardware(name: str) -> HardwareSpec:

@@ -9,7 +9,8 @@ Scheme dispatch goes through the SchemeRegistry — the executors defined
 here register at import for the built-ins:
   global   — XLA dot + Hari-style global check using the offline weight
              checksum (precompute via ``precompute_weight_checksums``).
-  block_*  — the fused Pallas kernel (kernels/ops.py), or the XLA
+  block_*  — the fused Pallas kernel (kernels/ops.py): compiled on a
+             TPU, interpret mode elsewhere; or, off a TPU only, the XLA
              emulation of its semantics when ``use_pallas=False``.
   replica  — fused kernel in replica mode (ablation baseline).
   none     — plain dot, clean CheckResult.
@@ -23,26 +24,35 @@ otherwise.  New code should build policies (and ``ProtectionPlan``s)
 directly and wrap them via ``ABFTConfig.from_policy`` where a config
 object is still required.
 
-Distribution note: under pjit/shard_map the GLOBAL path shards exactly like
-the dot it protects (the check einsums follow the same specs); the BLOCK
-path runs the Pallas kernel per shard — on a TP-sharded weight each shard
-checks its local sub-GEMM, which is precisely the paper's "smallest parallel
-subproblem" principle lifted one level up the hierarchy.
+Distribution note: under GSPMD the GLOBAL path (and the jnp emulation of
+the block schemes) shards exactly like the dot it protects — the check
+einsums follow the same specs.  A Pallas kernel is a custom call the
+partitioner cannot split, so when ``ABFTConfig.mesh`` names a
+tensor-parallel mesh (the serving ``MeshExecutor`` sets it) the kernel
+schemes run under ``shard_map`` on every TP-sharded site: each device
+checks its local sub-GEMM — column-parallel sites own a slice of the
+output columns, row-parallel sites a slice of the contraction, whose f32
+partial products are summed across the ``model`` axis before the cast to
+the output dtype — which is the paper's "smallest parallel subproblem"
+principle lifted one level up the hierarchy.  Sites the mesh does not
+shard (and any run without ``mesh``) call the kernel directly.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.analysis.markers import protection_scope
 from repro.core import checksums
 from repro.core.checksums import CheckResult
 from repro.core.faults import FaultSpec, inject_output_fault
-from repro.core.hardware import DEFAULT, HardwareSpec
+from repro.core.hardware import HardwareSpec, device_hardware, on_tpu
 from repro.core.intensity import GemmDims
 from repro.core.policy import (
     FixedPolicy,
@@ -82,11 +92,18 @@ class ABFTConfig:
     enabled: bool = True
     scheme: Scheme = Scheme.AUTO
     selector: SelectorConfig = SelectorConfig()
-    hardware: HardwareSpec = DEFAULT
+    # None: the device's own spec (core/hardware.py::device_hardware)
+    hardware: HardwareSpec | None = None
     blocks: BlockShape = BlockShape()
-    use_pallas: bool = True        # False: block schemes via the jnp oracle
+    # off a TPU: True runs block schemes through the Pallas kernel in
+    # interpret mode, False through the jnp emulation.  Ignored on a TPU,
+    # where they always run the compiled kernel (``runs_kernels``)
+    use_pallas: bool = True
     c_factor: float = 16.0
     protect_backward: bool = False  # optional dgrad/wgrad protection
+    # tensor-parallel jax.sharding.Mesh (with a 'model' axis) the kernel
+    # schemes run per shard on; None: no per-shard execution
+    mesh: Any = None
     # fused-ABFT flash attention backend (kernels/flash_attention.py):
     # protects attention's own GEMMs in-kernel and keeps score chunks in
     # VMEM (the §Perf-identified lever).  XLA chunked attention otherwise.
@@ -125,13 +142,26 @@ class ABFTConfig:
             return FixedPolicy(self.scheme)
         return policy_from_selector(self.selector)
 
+    @property
+    def runs_kernels(self) -> bool:
+        """Block schemes run the Pallas kernel (compiled on a TPU,
+        interpret mode elsewhere) rather than the jnp emulation."""
+        return self.use_pallas or on_tpu()
+
+    @property
+    def hw(self) -> HardwareSpec:
+        """The spec selection runs against: ``hardware`` if given, else
+        the device's (resolved at first use, never at import)."""
+        return self.hardware if self.hardware is not None \
+            else device_hardware()
+
     def resolve(self, dims: GemmDims, first_layer: bool = False):
         """Scheme for one GEMM shape (Scheme enum for built-ins, name
         string for registered plug-in schemes).  Passes itself as the
         policy's ``cfg`` so registry availability predicates see the
         active backend."""
         return self.effective_policy().select(
-            dims, self.hardware, first_layer=first_layer,
+            dims, self.hw, first_layer=first_layer,
             cfg=self).scheme
 
     @staticmethod
@@ -189,8 +219,77 @@ def protected_matmul(
     scheme = cfg.resolve(dims, first_layer=first_layer)
     executor = default_registry().executor(scheme)
     with protection_scope(scheme_name_of(scheme), site):
+        dim = _kernel_shard_dim(cfg, scheme, site, w)
+        if dim is not None:
+            return _per_shard(executor, x, w, cfg, dim,
+                              out_dtype=out_dtype, fault=fault)
         return executor(x, w, cfg, wsums=wsums, out_dtype=out_dtype,
                         fault=fault)
+
+
+# ------------------------------------------------ per-shard kernel schemes
+KERNEL_SCHEMES = ("block_1s", "block_2s", "replica")
+
+
+def _kernel_shard_dim(cfg: ABFTConfig, scheme, site: str, w) -> str | None:
+    """The GEMM dim ('n' column-parallel, 'k' row-parallel) the mesh's
+    'model' axis shards at this site when a Pallas kernel runs it, else
+    None.  Mirrors the parameter specs: a dim the axis does not divide
+    stays replicated (distributed/sharding.py::sanitize_spec)."""
+    if (cfg.mesh is None or not cfg.runs_kernels
+            or scheme_name_of(scheme) not in KERNEL_SCHEMES):
+        return None
+    from repro.models.counting import TP_SHARD_DIM
+
+    tp = cfg.mesh.shape["model"]
+    dim = TP_SHARD_DIM.get(site)
+    if tp == 1 or dim is None:
+        return None
+    size = w.shape[1] if dim == "n" else w.shape[0]
+    return dim if size % tp == 0 else None
+
+
+def _per_shard(executor, x, w, cfg: ABFTConfig, dim: str, *, out_dtype,
+               fault):
+    """Run a kernel scheme on each device's shard under ``shard_map``
+    (module docstring).  The flag is the OR over shards; residual and
+    threshold fold to the worst shard's max residual and min threshold.
+    A fault's global column is translated to the shard owning it
+    (column-parallel) or injected on shard 0 (row-parallel)."""
+    lead = (None,) * (x.ndim - 1)
+    if dim == "n":
+        specs = (P(), P(None, "model"), P(*lead, "model"))
+        local_dtype = out_dtype
+    else:
+        specs = (P(*lead, "model"), P("model", None), P())
+        local_dtype = jnp.float32       # partials sum in f32, then cast
+    fault = fault if fault is not None else FaultSpec.none()
+
+    def body(xs, ws, f):
+        s = jax.lax.axis_index("model")
+        if dim == "n":
+            n_loc = ws.shape[1]
+            col = f.col - s * n_loc
+            on = (col >= 0) & (col < n_loc)
+            f = f._replace(col=col, enabled=f.enabled * on)
+        else:
+            f = f._replace(enabled=f.enabled * (s == 0))
+        y, chk = executor(xs, ws, cfg, wsums=None, out_dtype=local_dtype,
+                          fault=f)
+        if dim == "k":
+            y = jax.lax.psum(y, "model").astype(out_dtype)
+        flag = jax.lax.psum(chk.flag.astype(jnp.int32), "model") > 0
+        res = jax.lax.pmax(jnp.max(chk.residual), "model")
+        tau = jax.lax.pmin(jnp.min(chk.threshold), "model")
+        return y, CheckResult(flag=flag, residual=res, threshold=tau)
+
+    x_spec, w_spec, y_spec = specs
+    f_spec = FaultSpec(*([P()] * len(FaultSpec._fields)))
+    # check_vma=False: a pallas_call's outputs carry no varying-axes type
+    return jax.shard_map(
+        body, mesh=cfg.mesh, in_specs=(x_spec, w_spec, f_spec),
+        out_specs=(y_spec, CheckResult(P(), P(), P())), check_vma=False,
+    )(x, w, fault)
 
 
 # ------------------------------------------------------------- executors
@@ -222,18 +321,18 @@ def _exec_global(x, w, cfg, *, wsums, out_dtype, fault):
 
 
 def _block_executor(mode: str):
-    """Block-level schemes — fused Pallas kernel, or the XLA emulation of
-    the fused kernel's *semantics* when ``use_pallas=False`` (used inside
-    the 512-device dry-run, where interpret-mode pallas_call cannot
-    lower): the one-sided check with the weight checksum recomputed
-    inline, as the kernel does.  Sharding-friendly: pure einsums, no
-    reshapes of sharded dims.  On real TPU the Pallas kernel replaces
-    this path; its internal costs are modeled analytically for the
-    roofline since a custom-call's internals are opaque to cost_analysis
-    either way."""
+    """Block-level schemes — the fused Pallas kernel, or the XLA
+    emulation of the fused kernel's *semantics* when ``use_pallas=False``
+    (used on CPU, e.g. inside the 512-device dry-run, where
+    interpret-mode pallas_call cannot lower): the one-sided check with
+    the weight checksum recomputed inline, as the kernel does.
+    Sharding-friendly: pure einsums, no reshapes of sharded dims.  On a
+    TPU the compiled kernel always runs (``ABFTConfig.runs_kernels``);
+    its internal costs are modeled analytically for the roofline since a
+    custom-call's internals are opaque to cost_analysis either way."""
 
     def _exec(x, w, cfg, *, wsums, out_dtype, fault):
-        if cfg.use_pallas:
+        if cfg.runs_kernels:
             from repro.kernels import ops
 
             return ops.abft_matmul(
@@ -251,10 +350,12 @@ def _block_executor(mode: str):
         w_sum = jnp.sum(w, axis=-1, dtype=f32)
         w_abs = jnp.sum(jnp.abs(w), axis=-1, dtype=f32)
         check = jnp.einsum("...mk,k->...m", x, w_sum.astype(x.dtype),
-                           preferred_element_type=f32)
+                           preferred_element_type=f32,
+                           precision=checksums.CHECK_PRECISION)
         bound = jnp.einsum("...mk,k->...m", jnp.abs(x),
                            w_abs.astype(x.dtype),
-                           preferred_element_type=f32)
+                           preferred_element_type=f32,
+                           precision=checksums.CHECK_PRECISION)
         yf = y.astype(f32)
         rowsum = jnp.sum(y, axis=-1, dtype=f32)
         res = jnp.abs(check - rowsum)

@@ -33,14 +33,19 @@ soft error in the MXU that the independent VPU checksum data path does not
 see (paper §2.3 fault model).
 
 VMEM budget per grid cell (bf16 operands, f32 accumulators):
-    bm·bk·2 + bk·bn·2 + bm·bn·4 + O(bm) bytes
-with the default (bm, bk, bn) = (256, 512, 256): 0.25 + 0.25 + 0.25 MiB
-≈ 0.78 MiB — comfortably inside a v5e core's VMEM even with double
+    bm·bk·2 + bk·bn·2 + bm·bn·4 + 2·bm·128·4 bytes
+with the default (bm, bk, bn) = (256, 512, 256): 0.25 + 0.25 + 0.25 +
+0.25 MiB ≈ 1 MiB — comfortably inside a v5e core's VMEM even with double
 buffering; all tile dims are multiples of the 128-lane MXU width.
 
-The tiny per-block residual outputs are logical shape (gm, gn, bm); on a
-real TPU these are metadata (≪ output bytes) and their layout is padded by
-Mosaic.  Kernels are validated in interpret mode against ref.py.
+Output layout (the TPU's (8, 128) tiling rule): the per-row checksum and
+bound accumulators are (bm, 128) lane-replicated tiles, and the residual
+and bound outputs are lane-dense ``(M, gn·128)`` arrays written one
+(bm, 128) tile per grid cell ('2s': ``(gm·8, gn·128)``, one (8, 128) tile
+holding the block scalar).  The wrapper (ops.py) reads lane 0 and
+reshapes to the logical (gm, gn, bm) / (gm, gn) residuals.  The fault
+vectors live in SMEM.  Kernels are validated in interpret mode against
+ref.py and compiled for a described v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -57,28 +62,30 @@ F32 = jnp.float32
 MODES = ("1s", "2s", "replica")
 
 
-def _apply_fault(acc, fault_idx, fault_val, block_i, block_j):
+LANES = 128          # lane width of a vreg: one output tile column
+
+
+def _apply_fault(acc, fault_idx_ref, fault_val_ref, block_i, block_j):
     """Corrupt one element of the f32 accumulator tile per the fault spec.
 
-    fault_idx: (8,) int32 [block_i, block_j, row_in_block, col_in_block,
-                           enabled, bit, _, _];  fault_val: (1,) f32 delta.
+    fault_idx (SMEM): (8,) int32 [block_i, block_j, row_in_block,
+    col_in_block, enabled, bit, _, _];  fault_val (SMEM): (1,) f32 delta.
     """
     bm, bn = acc.shape
     here = (
-        (fault_idx[4] == 1)
-        & (fault_idx[0] == block_i)
-        & (fault_idx[1] == block_j)
+        (fault_idx_ref[4] == 1)
+        & (fault_idx_ref[0] == block_i)
+        & (fault_idx_ref[1] == block_j)
     )
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-    mask = (rows == fault_idx[2]) & (cols == fault_idx[3]) & here
+    mask = (rows == fault_idx_ref[2]) & (cols == fault_idx_ref[3]) & here
 
-    bit = fault_idx[5]
-    raw = jax.lax.bitcast_convert_type(acc, jnp.uint32)
-    flip_mask = (jnp.ones((), jnp.uint32) << jnp.maximum(bit, 0).astype(
-        jnp.uint32))
+    bit = fault_idx_ref[5]
+    raw = jax.lax.bitcast_convert_type(acc, jnp.int32)
+    flip_mask = jnp.left_shift(jnp.int32(1), jnp.maximum(bit, 0))
     flipped = jax.lax.bitcast_convert_type(raw ^ flip_mask, F32)
-    corrupted = jnp.where(bit >= 0, flipped, acc + fault_val[0])
+    corrupted = jnp.where(bit >= 0, flipped, acc + fault_val_ref[0])
     return jnp.where(mask, corrupted, acc)
 
 
@@ -109,44 +116,48 @@ def _kernel(
 
     af = a.astype(F32)
     bf = b.astype(F32)
+    # checksum/bound accumulators are (rows, LANES) tiles whose lanes all
+    # hold the same value: the keepdims reductions below broadcast into them
     if mode == "1s":
         b_sum = jnp.sum(bf, axis=1)                     # (bk,)  VPU
         b_abs = jnp.sum(jnp.abs(bf), axis=1)            # (bk,)  VPU
         # Weighted row-sum: Σ_k A[:, k] * b_sum[k] — VPU multiply-reduce,
         # NOT an MXU matvec (DESIGN.md §2).
-        chk_ref[...] += jnp.sum(af * b_sum[None, :], axis=1)
-        bnd_acc_ref[...] += jnp.sum(jnp.abs(af) * b_abs[None, :], axis=1)
+        chk_ref[...] += jnp.sum(af * b_sum[None, :], axis=1, keepdims=True)
+        bnd_acc_ref[...] += jnp.sum(jnp.abs(af) * b_abs[None, :], axis=1,
+                                    keepdims=True)
     elif mode == "2s":
-        a_sum = jnp.sum(af, axis=0)                     # (bk,)
+        a_sum = jnp.sum(af, axis=0, keepdims=True)      # (1, bk)
+        a_abs = jnp.sum(jnp.abs(af), axis=0, keepdims=True)
         b_sum = jnp.sum(bf, axis=1)                     # (bk,)
-        a_abs = jnp.sum(jnp.abs(af), axis=0)
         b_abs = jnp.sum(jnp.abs(bf), axis=1)
-        chk_ref[0] += jnp.sum(a_sum * b_sum)
-        bnd_acc_ref[0] += jnp.sum(a_abs * b_abs)
+        chk_ref[...] += jnp.sum(a_sum * b_sum[None, :], axis=1,
+                                keepdims=True)
+        bnd_acc_ref[...] += jnp.sum(a_abs * b_abs[None, :], axis=1,
+                                    keepdims=True)
     elif mode == "replica":
         # Redundant MXU pass, single-vector accumulation (paper §4).
         redo = jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())), preferred_element_type=F32
         )
-        chk_ref[...] += jnp.sum(redo, axis=1)
-        bnd_acc_ref[...] += jnp.sum(jnp.abs(redo), axis=1)
+        chk_ref[...] += jnp.sum(redo, axis=1, keepdims=True)
+        bnd_acc_ref[...] += jnp.sum(jnp.abs(redo), axis=1, keepdims=True)
     else:
         raise ValueError(f"unknown mode {mode!r}")
 
     @pl.when(k == gk - 1)
     def _finalize():
-        acc = _apply_fault(
-            acc_ref[...], fault_idx_ref[...], fault_val_ref[...], i, j
-        )
+        acc = _apply_fault(acc_ref[...], fault_idx_ref, fault_val_ref, i, j)
         y_ref[...] = acc.astype(out_dtype)
+        rowsum = jnp.sum(acc, axis=1, keepdims=True)    # (bm, 1) VPU
         if mode == "2s":
-            total = jnp.sum(acc)
-            res_ref[0, 0] = jnp.abs(chk_ref[0] - total)
-            bnd_ref[0, 0] = bnd_acc_ref[0]
+            total = jnp.sum(rowsum, axis=0, keepdims=True)        # (1, 1)
+            res = jnp.abs(chk_ref[...] - total)                   # (1, L)
+            res_ref[...] = jnp.broadcast_to(res, res_ref.shape)
+            bnd_ref[...] = jnp.broadcast_to(bnd_acc_ref[...], bnd_ref.shape)
         else:
-            rowsum = jnp.sum(acc, axis=1)               # (bm,) VPU
-            res_ref[0, 0, :] = jnp.abs(chk_ref[...] - rowsum)
-            bnd_ref[0, 0, :] = bnd_acc_ref[...]
+            res_ref[...] = jnp.abs(chk_ref[...] - rowsum)
+            bnd_ref[...] = bnd_acc_ref[...]
 
 
 def abft_matmul_kernel(
@@ -164,8 +175,10 @@ def abft_matmul_kernel(
 ):
     """Raw kernel entry; shapes must already be padded to block multiples.
 
-    x: (M, K), w: (K, N) -> y (M, N) in out_dtype,
-    res/bnd: (gm, gn, bm) f32 ('1s'/'replica') or (gm, gn) f32 ('2s').
+    x: (M, K), w: (K, N) -> y (M, N) in out_dtype, plus lane-dense f32
+    res/bnd tiles: (M, gn·128) for '1s'/'replica' (row r of block column
+    j in lanes [128j, 128j + 128)), (gm·8, gn·128) for '2s' (one block
+    scalar per (8, 128) tile).  ``ops.abft_matmul`` unpacks them.
     """
     m, k = x.shape
     k2, n = w.shape
@@ -174,14 +187,11 @@ def abft_matmul_kernel(
         (m, k, n), (bm, bk, bn))
     gm, gk, gn = m // bm, k // bk, n // bn
 
-    if mode == "2s":
-        res_shape = jax.ShapeDtypeStruct((gm, gn), F32)
-        res_spec = pl.BlockSpec((1, 1), lambda i, j, kk: (i, j))
-        chk_shape = (1,)
-    else:
-        res_shape = jax.ShapeDtypeStruct((gm, gn, bm), F32)
-        res_spec = pl.BlockSpec((1, 1, bm), lambda i, j, kk: (i, j, 0))
-        chk_shape = (bm,)
+    rows = 8 if mode == "2s" else bm
+    res_shape = jax.ShapeDtypeStruct((gm * rows, gn * LANES), F32)
+    res_spec = pl.BlockSpec((rows, LANES), lambda i, j, kk: (i, j))
+    chk_rows = 1 if mode == "2s" else bm
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     kernel = functools.partial(_kernel, gk=gk, mode=mode, out_dtype=out_dtype)
 
@@ -191,8 +201,8 @@ def abft_matmul_kernel(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((8,), lambda i, j, kk: (0,)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
+            smem,
+            smem,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -205,9 +215,9 @@ def abft_matmul_kernel(
             res_shape,
         ],
         scratch_shapes=[
-            pltpu.VMEM((bm, bn), F32),   # main f32 accumulator tile
-            pltpu.VMEM(chk_shape, F32),  # ABFT checksum accumulator
-            pltpu.VMEM(chk_shape, F32),  # magnitude-bound accumulator
+            pltpu.VMEM((bm, bn), F32),              # main f32 accumulator
+            pltpu.VMEM((chk_rows, LANES), F32),     # ABFT checksum
+            pltpu.VMEM((chk_rows, LANES), F32),     # magnitude bound
         ],
         interpret=interpret,
     )(x, w, fault_idx, fault_val)

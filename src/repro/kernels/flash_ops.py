@@ -13,6 +13,7 @@ from repro.kernels.flash_attention import (
     flash_decode_kernel,
     flash_decode_paged_kernel,
 )
+from repro.kernels.ops import default_interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -23,7 +24,9 @@ def _attn_check(rs, bs, rp, bp, d: int, s: int,
                 c_factor: float) -> CheckResult:
     """Fold the per-tile residual/bound vectors of both attention GEMMs
     (scores: reduction depth ``d``; PV: reduction depth ``s``) into one
-    CheckResult — shared by every flash entry point."""
+    CheckResult — shared by every flash entry point.  The kernels emit
+    lane-replicated (rows, 128) tiles; lane 0 carries the values."""
+    rs, bs, rp, bp = (a[..., 0] for a in (rs, bs, rp, bp))
     tau_s = ATOL + tolerance_scale(d, c=c_factor) * bs
     tau_pv = ATOL + tolerance_scale(s, c=c_factor) * bp
     flag = jnp.logical_or(flag_from(rs, tau_s), flag_from(rp, tau_pv))
@@ -51,7 +54,7 @@ def flash_attention(
     attention GEMMs (scores and PV).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     B, Lq, H, D = q.shape
     KV, Dv = k.shape[2], v.shape[3]
     if KV != H:
@@ -116,13 +119,10 @@ def flash_decode(
     (out (B, 1, H, Dv), CheckResult) covering both attention GEMMs.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     B, _, H, D = q.shape
     S, KV, Dv = k_cache.shape[1], k_cache.shape[2], v_cache.shape[3]
-    if KV != H:
-        rep = H // KV
-        k_cache = jnp.repeat(k_cache, rep, axis=2)
-        v_cache = jnp.repeat(v_cache, rep, axis=2)
+    G = H // KV
 
     bk_eff = min(bk, _round_up(S, 8))
     pk = _round_up(S, bk_eff) - S
@@ -130,18 +130,20 @@ def flash_decode(
     vp = jnp.pad(v_cache, ((0, 0), (0, pk), (0, 0), (0, 0)))
     lengths = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32), (B,))[:, None]    # (B, 1)
+    # q heads are stored kv-major (kv, group): each kernel call takes the
+    # G query heads of one kv head, so the cache is never head-replicated
+    qg = q[:, 0].reshape(B, KV, G, D)
 
-    def one_head(qh, kh, vh, ln):
+    def one_kv_head(qk, kh, vh, ln):
         return flash_decode_kernel(
-            qh, kh, vh, ln, bk=bk_eff, interpret=interpret,
+            qk, kh, vh, ln, bk=bk_eff, interpret=interpret,
             out_dtype=q.dtype)
 
-    f = jax.vmap(jax.vmap(one_head, in_axes=(0, 0, 0, None)),
+    f = jax.vmap(jax.vmap(one_kv_head, in_axes=(0, 0, 0, None)),
                  in_axes=(0, 0, 0, 0))
     o, rs, bs, rp, bp = f(
-        jnp.moveaxis(q, 2, 1), jnp.moveaxis(kp, 2, 1),
-        jnp.moveaxis(vp, 2, 1), lengths)
-    out = jnp.moveaxis(o, 1, 2)                            # (B, 1, H, Dv)
+        qg, jnp.moveaxis(kp, 2, 1), jnp.moveaxis(vp, 2, 1), lengths)
+    out = o.reshape(B, 1, H, Dv)             # (B, KV, G, Dv), kv-major
 
     return out, _attn_check(rs, bs, rp, bp, D, S, c_factor)
 
@@ -172,7 +174,7 @@ def flash_decode_paged(
     GEMMs.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     B, _, H, D = q.shape
     NB, BS, KV, Dv = v_pool.shape
     W = block_tables.shape[1]

@@ -15,12 +15,15 @@ import jax.numpy as jnp
 
 from repro.core.checksums import ATOL, CheckResult, flag_from, tolerance_scale
 from repro.core.faults import FaultSpec
+from repro.core.hardware import on_tpu
 from repro.core.schemes import BlockShape
-from repro.kernels.abft_matmul import F32, abft_matmul_kernel
+from repro.kernels.abft_matmul import F32, LANES, abft_matmul_kernel
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode everywhere except a TPU backend, where every Pallas
+    kernel in this package runs compiled (the flash wrappers share this)."""
+    return not on_tpu()
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -54,10 +57,42 @@ def _abft_matmul_padded(
         bm=bm, bk=bk, bn=bn, mode=mode, out_dtype=out_dtype,
         interpret=interpret,
     )
+    gm, gn = x.shape[0] // bm, w.shape[1] // bn
+    # lane-dense kernel tiles -> logical residuals: lane 0 of each tile
+    if mode == "2s":
+        res, bnd = res[::8, ::LANES], bnd[::8, ::LANES]           # (gm, gn)
+    else:                                                  # (gm, gn, bm)
+        res, bnd = (a[:, ::LANES].reshape(gm, bm, gn).transpose(0, 2, 1)
+                    for a in (res, bnd))
     k = x.shape[1]
     tau = ATOL + tolerance_scale(k, c=c_factor) * bnd
     flag = flag_from(res, tau)
     return y, res, tau, flag
+
+
+# A pallas_call has no transpose rule, so reverse mode (training through a
+# block scheme) gets an explicit VJP: the forward GEMM runs in the kernel,
+# the backward GEMMs are plain f32-accumulated dots (dgrad/wgrad are not
+# ABFT-protected).  ``static`` holds _abft_matmul_padded's static kwargs.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _abft_matmul_diff(x, w, fault_idx, fault_val, static):
+    return _abft_matmul_padded(x, w, fault_idx, fault_val, **dict(static))
+
+
+def _diff_fwd(x, w, fault_idx, fault_val, static):
+    out = _abft_matmul_padded(x, w, fault_idx, fault_val, **dict(static))
+    return out, (x, w)
+
+
+def _diff_bwd(static, saved, cts):
+    x, w = saved
+    gy = cts[0]
+    gx = jnp.matmul(gy, w.T, preferred_element_type=F32).astype(x.dtype)
+    gw = jnp.matmul(x.T, gy, preferred_element_type=F32).astype(w.dtype)
+    return gx, gw, None, None
+
+
+_abft_matmul_diff.defvjp(_diff_fwd, _diff_bwd)
 
 
 def abft_matmul(
@@ -108,11 +143,11 @@ def abft_matmul(
     ).astype(jnp.int32)
     fault_val = fault.delta.reshape((1,)).astype(F32)
 
-    y, res, tau, flag = _abft_matmul_padded(
+    y, res, tau, flag = _abft_matmul_diff(
         x2, wp, fault_idx, fault_val,
-        mode=mode, bm=bm, bk=bk, bn=bn,
-        out_dtype=jnp.dtype(out_dtype), interpret=interpret,
-        c_factor=c_factor,
+        (("mode", mode), ("bm", bm), ("bk", bk), ("bn", bn),
+         ("out_dtype", jnp.dtype(out_dtype)), ("interpret", interpret),
+         ("c_factor", c_factor)),
     )
     y = y[:m, :n0].reshape((*lead, m0, n0))
     return y, CheckResult(flag=flag, residual=res, threshold=tau)

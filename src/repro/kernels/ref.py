@@ -1,12 +1,14 @@
-"""Pure-jnp oracles for the fused ABFT matmul kernel.
+"""Pure-jnp oracles for the fused ABFT kernels.
 
 ``matmul_ref`` is the ground-truth GEMM.  ``abft_matmul_ref`` mirrors the
 kernel's blocked accumulation order exactly (k-chunked f32 sums) so the
 kernel's residual/bound outputs can be compared with tight tolerances.
+``attention_ref`` is plain softmax attention for the flash kernels.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
@@ -77,3 +79,26 @@ def abft_matmul_ref(
         bnd = jnp.abs(y_mat).sum(axis=3)
         return y2.astype(out_dtype), res, bnd
     return y2.astype(out_dtype), res, bnd.transpose(0, 2, 1)
+
+
+def attention_ref(q, k, v, *, causal: bool = True, lengths=None):
+    """Softmax attention in f32.  q: (B, Lq, H, D); k/v: (B, Lk, KV,
+    D[v]) with the H query heads grouped kv-major (head h reads kv head
+    h // (H // KV)); ``causal`` aligns query i with key i; ``lengths``
+    (B,) masks each row's keys at and past its length.  Returns
+    (B, Lq, H, Dv) f32."""
+    B, Lq, H, D = q.shape
+    Lk, KV = k.shape[1], k.shape[2]
+    qg = q.astype(F32).reshape(B, Lq, KV, H // KV, D)
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(F32),
+                   precision="highest") * D ** -0.5
+    mask = jnp.ones((B, 1, 1, Lq, Lk), bool)
+    if causal:
+        mask &= jnp.tril(jnp.ones((Lq, Lk), bool))
+    if lengths is not None:
+        valid = jnp.arange(Lk)[None, :] < jnp.asarray(lengths)[:, None]
+        mask &= valid[:, None, None, None, :]
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    o = jnp.einsum("bkgqs,bskv->bqkgv", p, v.astype(F32),
+                   precision="highest")
+    return o.reshape(B, Lq, H, v.shape[-1])

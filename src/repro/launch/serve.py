@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, scaled_down
 from repro.core.faults import FaultModel, FaultSpec
+from repro.core.hardware import on_tpu
 from repro.core.policy import (
     ErrorAdaptivePolicy,
     FixedPolicy,
@@ -42,6 +43,7 @@ from repro.core.policy import (
 )
 from repro.core.protected import ABFTConfig
 from repro.core.schemes import Scheme
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelFault, build_model
 from repro.obs import ENGINE_COUNTERS, EngineTelemetry
 from repro.runtime.heartbeat import HeartbeatMonitor
@@ -62,7 +64,38 @@ def _draft_len(v: str):
     return int(v)
 
 
+def serving_dtype():
+    """bfloat16 on a TPU (the chip's precision); float32 elsewhere, so
+    CPU runs and tests keep their f32 numerics."""
+    return jnp.bfloat16 if on_tpu() else jnp.float32
+
+
+def abft_config(mode: str, *, adaptive: bool = False,
+                escalate_threshold: float = 0.05) -> ABFTConfig:
+    """--abft mode -> config.  Block schemes run the compiled kernel on a
+    TPU and the jnp emulation on CPU; the hardware spec is the device's
+    (``ABFTConfig.hw``)."""
+    if mode == "off":
+        return ABFTConfig.off()
+    base = (IntensityGuidedPolicy() if mode == "auto"
+            else FixedPolicy(Scheme(mode)))
+    if adaptive:
+        base = ErrorAdaptivePolicy(
+            base, detection_threshold=escalate_threshold)
+    return ABFTConfig.from_policy(base, use_pallas=on_tpu())
+
+
+# the --inject-faults site: one value fault in layer 0's MLP down
+# projection, armed from engine step 3
+INJECT_STEP = 3
+
+
+def injected_fault() -> ModelFault:
+    return ModelFault.at(0, "mlp_down", FaultSpec.value(0, 1, 1e5))
+
+
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ALL_ARCHS, default="llama3.2-1b")
     ap.add_argument("--scale", choices=["full", "smoke"], default="smoke")
@@ -177,16 +210,10 @@ def main(argv=None) -> int:
     if args.scale == "smoke":
         cfg = scaled_down(cfg)
     model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0), dtype=jnp.float32)
-    if args.abft == "off":
-        abft = ABFTConfig.off()
-    else:
-        base = (IntensityGuidedPolicy() if args.abft == "auto"
-                else FixedPolicy(Scheme(args.abft)))
-        if args.adaptive:
-            base = ErrorAdaptivePolicy(
-                base, detection_threshold=args.escalate_threshold)
-        abft = ABFTConfig.from_policy(base, use_pallas=False)
+    dtype = serving_dtype()
+    params = model.init_params(jax.random.PRNGKey(0), dtype=dtype)
+    abft = abft_config(args.abft, adaptive=args.adaptive,
+                       escalate_threshold=args.escalate_threshold)
     fault_model = None
     if args.fault_rate > 0:
         fault_model = FaultModel(
@@ -196,7 +223,7 @@ def main(argv=None) -> int:
                             if args.fault_kind == "permanent" else 0.0),
             permanent_duration=args.fault_duration,
             seed=args.fault_seed, layers=cfg.n_layers,
-            dtype=jnp.float32,
+            dtype=dtype,
             magnitude=args.fault_magnitude or None)
     policy = RecoveryPolicy(
         max_retries=args.max_retries,
@@ -218,7 +245,7 @@ def main(argv=None) -> int:
             trace_sink=sink)
     engine = ServeEngine(model, params, slots=args.slots,
                          max_len=args.max_len, abft=abft,
-                         dtype=jnp.float32, policy=policy, mesh=args.mesh,
+                         dtype=dtype, policy=policy, mesh=args.mesh,
                          cache_kind=args.cache, block_size=args.block_size,
                          num_blocks=args.num_blocks,
                          prefix_sharing=args.prefix_sharing,
@@ -257,8 +284,7 @@ def main(argv=None) -> int:
     ]
     fault_at = None
     if args.inject_faults:
-        fault_at = (3, ModelFault.at(
-            0, "mlp_down", FaultSpec.value(0, 1, 1e5)))
+        fault_at = (INJECT_STEP, injected_fault())
     # monotonic clock everywhere latency is derived: wall-clock
     # adjustments must never produce negative TTFT/ITL
     t0 = time.perf_counter()

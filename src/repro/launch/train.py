@@ -18,10 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ALL_ARCHS, get_config, scaled_down
-from repro.core.policy import FixedPolicy, IntensityGuidedPolicy
-from repro.core.protected import ABFTConfig
-from repro.core.schemes import Scheme
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import abft_config
 from repro.models import build_model
 from repro.models.counting import count_params
 from repro.train import OptConfig, TrainConfig
@@ -42,19 +41,8 @@ def scale_config(cfg, scale: str):
     raise ValueError(scale)
 
 
-def abft_config(mode: str) -> ABFTConfig:
-    """Mode string -> ABFT config via the ProtectionPolicy API (the
-    ABFTConfig facade only carries execution knobs)."""
-    if mode == "off":
-        return ABFTConfig.off()
-    if mode == "auto":
-        return ABFTConfig.from_policy(IntensityGuidedPolicy(),
-                                      use_pallas=False)
-    return ABFTConfig.from_policy(FixedPolicy(Scheme(mode)),
-                                  use_pallas=False)
-
-
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ALL_ARCHS, default="llama3.2-1b")
     ap.add_argument("--scale", choices=["full", "smoke", "100m"],

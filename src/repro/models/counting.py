@@ -20,7 +20,7 @@ from repro.models.model import layer_tags
 # down, ssm out_proj, ...).  Sites absent here are replicated (mla.q_a /
 # kv_a low-rank projections, ssm.in_bc, moe.router, vision.proj) and
 # keep their full dims on every shard.
-_TP_SHARD_DIM = {
+TP_SHARD_DIM = {
     "attn.q": "n", "attn.k": "n", "attn.v": "n", "attn.o": "k",
     "mla.q_b": "n", "mla.out": "k",
     "ssm.in_z": "n", "ssm.in_x": "n", "ssm.in_dt": "n", "ssm.out": "k",
@@ -53,7 +53,7 @@ def shard_gemms(sites: dict, cfg: ModelConfig, model_parallel: int) -> dict:
     ep_fits = cfg.n_experts % tp == 0 if cfg.n_experts else True
     out = {}
     for name, (d, count) in sites.items():
-        dim = _TP_SHARD_DIM.get(name)
+        dim = TP_SHARD_DIM.get(name)
         if name in ("moe.expert_up", "moe.expert_down"):
             if ep_fits:
                 count = max(1, count // tp)

@@ -637,11 +637,11 @@ class Model:
         dim (batch*seq for full passes; batch/slots for decode);
         ``model_parallel=k`` compiles one shard's post-sharding shapes
         (the per-device plan on a k-wide model axis)."""
-        from repro.core.hardware import DEFAULT
+        from repro.core.hardware import device_hardware
         from repro.core.policy import ProtectionPlan
 
         return ProtectionPlan.for_model(
-            self.cfg, hw=hw or DEFAULT, policy=policy, phase=phase,
+            self.cfg, hw=hw or device_hardware(), policy=policy, phase=phase,
             n_tokens=n_tokens, dtype_bytes=dtype_bytes,
             model_parallel=model_parallel)
 

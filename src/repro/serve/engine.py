@@ -325,6 +325,11 @@ class ServeEngine:
         else:
             self.executor = MeshExecutor(model, params, mesh=mesh,
                                          dtype=dtype, hints=hints)
+            if abft.runs_kernels:
+                # a Pallas kernel is not partitioned by GSPMD: kernel
+                # schemes run per shard (core/protected.py::_per_shard)
+                abft = dataclasses.replace(abft, mesh=self.executor.mesh)
+                self.abft = abft
         # --- error-rate-adaptive protection (ErrorAdaptivePolicy):
         # schemes resolve at TRACE time from the LayerCtx's config, so a
         # runtime level change cannot ride one mutable policy inside one

@@ -86,7 +86,7 @@ class LocalExecutor:
         """Compile the ProtectionPlan for THIS executor's hardware view:
         per-shard GEMM shapes under ``model_parallel``-way TP."""
         return self.model.protection_plan(
-            hw=abft.hardware, policy=abft.effective_policy(),
+            hw=abft.hw, policy=abft.effective_policy(),
             phase="serve", n_tokens=slots, dtype_bytes=self.dtype_bytes,
             model_parallel=self.model_parallel)
 

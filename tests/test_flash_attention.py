@@ -1,32 +1,16 @@
-"""Fused-ABFT flash attention kernel: interpret-mode validation against a
-naive softmax-attention oracle + fault detection through the online
+"""Fused-ABFT flash attention kernel: interpret-mode validation against the
+softmax-attention oracle (kernels/ref.py) + fault detection through the online
 softmax rescaling."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.faults import FaultSpec
 from repro.kernels.flash_ops import flash_attention
+from repro.kernels.ref import attention_ref
 
 F32 = jnp.float32
-
-
-def _naive(q, k, v, causal=True):
-    B, Lq, H, D = q.shape
-    KV = k.shape[2]
-    if KV != H:
-        rep = H // KV
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(F32), k.astype(F32))
-    s = s * (D ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((Lq, k.shape[1]), bool))
-        s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhv->bqhv", p, v.astype(F32))
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -40,7 +24,7 @@ def test_matches_naive_attention(rng, shape, causal):
     k = jnp.asarray(rng.standard_normal((B, L, KV, D)), F32)
     v = jnp.asarray(rng.standard_normal((B, L, KV, D)), F32)
     o, chk = flash_attention(q, k, v, causal=causal, bq=32, bk=32)
-    ref = _naive(q, k, v, causal=causal)
+    ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(
         np.asarray(o), np.asarray(ref), rtol=2e-4, atol=2e-4)
     assert not bool(chk.flag), (
@@ -54,7 +38,7 @@ def test_bf16_no_false_positive(rng):
     v = jnp.asarray(rng.standard_normal((1, 64, 2, 16)), jnp.bfloat16)
     o, chk = flash_attention(q, k, v, causal=True, bq=32, bk=32)
     assert not bool(chk.flag)
-    ref = _naive(q, k, v)
+    ref = attention_ref(q, k, v)
     np.testing.assert_allclose(
         np.asarray(o, np.float32), np.asarray(ref), rtol=5e-2, atol=5e-2)
 
@@ -85,7 +69,7 @@ def test_padded_lengths(rng):
     k = jnp.asarray(rng.standard_normal((1, 40, 2, 16)), F32)
     v = jnp.asarray(rng.standard_normal((1, 40, 2, 16)), F32)
     o, chk = flash_attention(q, k, v, causal=True, bq=16, bk=16)
-    ref = _naive(q, k, v, causal=True)
+    ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(o), np.asarray(ref), rtol=2e-4, atol=2e-4)
     assert o.shape == (1, 40, 2, 16)

@@ -61,6 +61,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, scaled_down
 from repro.core import ABFTConfig, Scheme
 from repro.distributed import sharding as shd
+from repro.distributed.mesh import build_mesh
 from repro.models import build_model
 from repro.models.layers import ShardingHints
 from repro.train import OptConfig, TrainConfig, init_opt_state, make_train_step
@@ -68,7 +69,7 @@ from repro.train import OptConfig, TrainConfig, init_opt_state, make_train_step
 cfg = scaled_down(get_config("qwen2-moe-a2.7b"), n_layers=2, n_experts=4,
                   d_model=64, vocab_size=128)
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = build_mesh(model=4, data=2)
 hints = ShardingHints(dp=("data",), dp_size=2, moe_mode="ep")
 abft = ABFTConfig(scheme=Scheme.AUTO, use_pallas=False)
 tcfg = TrainConfig(opt=OptConfig(lr=1e-3))

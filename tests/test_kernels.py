@@ -176,3 +176,23 @@ def test_block_clamping_thin_gemm(rng):
         np.asarray(y), np.asarray(matmul_ref(x, w, jnp.float32)),
         rtol=5e-4, atol=5e-4)
     assert not bool(chk.flag)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_kernel_gradients_match_plain_dot(rng, mode):
+    """Reverse mode through the kernel (training on a block scheme): the
+    gradients equal those of the plain dot, padding included."""
+    x = jnp.asarray(rng.standard_normal((2, 12, 200)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((200, 130)), jnp.float32)
+
+    def loss(mm):
+        return lambda a, b: jnp.sum(jnp.tanh(mm(a, b)))
+
+    kern = loss(lambda a, b: abft_matmul(a, b, mode=mode)[0])
+    ref = loss(lambda a, b: jnp.matmul(a, b, precision="highest"))
+    got = jax.grad(kern, argnums=(0, 1))(x, w)
+    want = jax.grad(ref, argnums=(0, 1))(x, w)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=5e-4, atol=5e-4)

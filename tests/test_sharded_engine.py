@@ -19,9 +19,16 @@ runs them):
     k in {2, 4} — dense, paged, chunked + prefix-shared, and under
     injected prefill/decode faults with retry and hard-fault eviction.
 
-bf16 everywhere: per-device partial GEMMs accumulate in f32 and round
-below bf16 output precision, so TP's psum reordering cannot perturb
-the streams (full-f32 models can differ in the last ulp)."""
+bf16 everywhere: at these small widths per-device partial GEMMs
+accumulate in f32 and round below bf16 output precision, so TP's psum
+reordering does not perturb the streams (full-f32 models can differ in
+the last ulp).  At full width on a TPU a near-tie in the argmax can
+flip; ``chip_smoke.py --mesh 4`` compares the logits there instead."""
+
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -256,6 +263,70 @@ def test_sharded_plan_rows_in_telemetry(setup):
     # the exported rows ARE the per-shard plan: dims match the TP=2 plan
     assert {e["args"]["layer"] for e in rows} == \
         {r["layer"] for r in eng.plan.report_rows()}
+
+
+# ------------------------------------- kernel schemes per shard (shard_map)
+_PER_SHARD = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import dataclasses, json
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.faults import FaultSpec
+from repro.core.policy import FixedPolicy
+from repro.core.protected import ABFTConfig, protected_matmul
+from repro.core.schemes import Scheme
+from repro.distributed.mesh import build_mesh
+
+one = ABFTConfig.from_policy(FixedPolicy(Scheme.BLOCK_1S))
+tp4 = dataclasses.replace(one, mesh=build_mesh(model=4))
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+out = {}
+# attn.q is column-parallel (n sharded), attn.o row-parallel (k sharded)
+for site, (k, n) in (("attn.q", (64, 256)), ("attn.o", (256, 64))):
+    x = jax.random.normal(ks[0], (2, 8, k), jnp.float32)
+    w = jax.random.normal(ks[1], (k, n), jnp.float32) * k ** -0.5
+    fault = FaultSpec.value(5, n - 7, 1e3)      # last shard's columns
+    res = {}
+    for name, cfg in (("one", one), ("tp4", tp4)):
+        f = jax.jit(lambda x, w, flt, cfg=cfg: protected_matmul(
+            x, w, cfg, fault=flt, site=site))
+        y, c = f(x, w, FaultSpec.none())
+        yf, cf = f(x, w, fault)
+        hlo = f.lower(x, w, fault).as_text()
+        res[name] = (np.asarray(y), bool(c.flag), np.asarray(yf),
+                     bool(cf.flag), "shard_map" in hlo or "sdy.manual" in hlo)
+    y1, flag1, yf1, fflag1, _ = res["one"]
+    y4, flag4, yf4, fflag4, manual = res["tp4"]
+    out[site] = {
+        "y_close": bool(np.allclose(y4, y1, rtol=1e-5, atol=1e-5)),
+        "clean_flags": [flag1, flag4], "fault_flags": [fflag1, fflag4],
+        "fault_lands_same": bool(np.allclose(yf4, yf1, rtol=1e-5,
+                                             atol=1e-3)),
+        "per_shard": manual,
+    }
+print(json.dumps(out))
+"""
+
+
+def test_kernel_schemes_run_per_shard_on_a_tp_mesh():
+    """On a 4-wide model axis a kernel scheme runs under shard_map on the
+    column- and row-parallel sites: same output as one device, clean
+    runs unflagged, and an injected fault in the last shard's columns
+    flagged and landing on the same output element."""
+    res = subprocess.run(
+        [sys.executable, "-c", _PER_SHARD], capture_output=True,
+        text=True, timeout=600,
+        env={**os.environ, "PYTHONPATH": "src"})
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    for site in ("attn.q", "attn.o"):
+        r = out[site]
+        assert r["per_shard"], site
+        assert r["y_close"], site
+        assert r["clean_flags"] == [False, False], site
+        assert r["fault_flags"] == [True, True], site
+        assert r["fault_lands_same"], site
 
 
 if __name__ == "__main__":
