@@ -1,0 +1,2 @@
+"""Protected GEMM sites' share of their roofline, docs cells."""
+from bench.readers import gemm_roofline as read  # noqa: F401
