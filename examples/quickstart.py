@@ -79,10 +79,11 @@ print(f"\n2c) coverage audit: protected={rep.protected_fraction:.2f}; "
 
 # ---------------------------------------------------- 2d. observability
 # the serving telemetry stack is dependency-free and usable standalone:
-# a metrics registry (JSON + Prometheus exposition), a Perfetto-JSON
-# span tracer, and the rolling fault-rate monitor that feeds adaptive
-# protection (ROADMAP 5b).  The serve driver wires all three behind
-# --metrics-out / --trace-out / --log-events.
+# a metrics registry (JSON export), a span tracer (jax.profiler events,
+# recorded as Perfetto JSON when enabled), and the rolling fault-rate
+# monitor that feeds adaptive protection (ROADMAP 5b).  The serve
+# driver wires all three behind --metrics-out / --trace-out /
+# --log-events.
 from repro.obs import FaultRateMonitor, MetricsRegistry, Tracer
 
 reg = MetricsRegistry()
@@ -94,15 +95,15 @@ lat = reg.histogram("serve_step_latency_seconds", "step wall time",
 lat.observe(0.004)
 
 tracer = Tracer()
-with tracer.span("decode_step", {"tokens": 8}):
-    with tracer.span("abft_check"):
+with tracer.span("serve.decode", {"rows": 8}):
+    with tracer.span("serve.decode.wait", {"what": "flag"}):
         pass
 tracer.instant("scheme_flip", {"scheme": "global", "intensity": 42.0})
 
 monitor = FaultRateMonitor(window=128)
 monitor.observe(steps=1, tokens=8, detections=1, retries=1)
 print("\n2d) telemetry:")
-print("   " + "\n   ".join(reg.render_prometheus().splitlines()[:4]))
+print(f"   metrics = {reg.names()}")
 print(f"   trace events = {len(tracer.events)}, windowed detection "
       f"rate = {monitor.window_detection_rate:.3f}/step")
 

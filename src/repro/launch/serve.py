@@ -17,9 +17,11 @@ wraps the base policy in an ``ErrorAdaptivePolicy`` that escalates to
 Telemetry flags (repro/obs): ``--metrics-out`` writes the metrics
 snapshot + fault-rate surface + final engine stats as one JSON artifact
 (``benchmarks/check_telemetry_schema.py`` validates it);
-``--trace-out`` writes a Chrome-trace/Perfetto JSON (load it at
-https://ui.perfetto.dev); ``--log-events`` streams every trace event as
-a JSON line to stderr while serving.
+``--trace-out`` writes the engine's host-phase spans (``serve.step``,
+``serve.decode.wait``, ... — the same spans any ``jax.profiler`` trace
+of the run holds) and instants as Chrome-trace/Perfetto JSON on the
+tracer's own clock (load it at https://ui.perfetto.dev); ``--log-events``
+streams every trace event as a JSON line to stderr while serving.
 """
 
 from __future__ import annotations
@@ -198,9 +200,12 @@ def main(argv=None) -> int:
                          "stats) as a JSON artifact")
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome-trace/Perfetto JSON of the "
-                         "serving run (spans: admit/prefill/decode_step/"
-                         "abft_retry/...; instants: scheme flips, "
-                         "evictions, fault detections)")
+                         "serving run on the tracer's own clock (host-"
+                         "phase spans serve.step, serve.schedule, "
+                         "serve.decode.{prepare,dispatch,wait,commit}, "
+                         "serve.retry, ..., the same spans a "
+                         "jax.profiler trace holds; instants: scheme "
+                         "flips, evictions, fault detections)")
     ap.add_argument("--log-events", action="store_true",
                     help="stream every trace event as a JSON line to "
                          "stderr (structured event log)")

@@ -1,6 +1,7 @@
-"""Serving observability: metrics registry (Prometheus/JSON export),
-structured span tracing (Chrome-trace/Perfetto JSON), and the
-fault-rate monitor feeding adaptive protection (ROADMAP item 5b)."""
+"""Serving observability: metrics registry (JSON export), host-phase
+spans (jax.profiler TraceMe events, optionally recorded as
+Chrome-trace/Perfetto JSON), and the fault-rate monitor feeding
+adaptive protection (ROADMAP item 5b)."""
 
 from repro.obs.faultrate import FaultRateMonitor
 from repro.obs.metrics import (

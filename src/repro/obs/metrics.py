@@ -1,8 +1,6 @@
 """Dependency-free metrics registry: Counter / Gauge / Histogram with
-label sets, bounded cardinality, and two export surfaces — a JSON
-``snapshot()`` (the benchmark/CI artifact format) and Prometheus
-text-exposition rendering (``render_prometheus()``) for scrape-style
-consumption.
+label sets, bounded cardinality, and a JSON ``snapshot()`` export (the
+benchmark/CI artifact format).
 
 Design constraints (why this is hand-rolled instead of a client lib):
 
@@ -56,25 +54,6 @@ class CardinalityError(ValueError):
 
 class RegistrationError(ValueError):
     """Conflicting re-registration (same name, different type/labels)."""
-
-
-def _escape_label_value(v: str) -> str:
-    return (v.replace("\\", "\\\\").replace("\n", "\\n")
-            .replace('"', '\\"'))
-
-
-def _escape_help(v: str) -> str:
-    return v.replace("\\", "\\\\").replace("\n", "\\n")
-
-
-def _fmt(v: float) -> str:
-    """Prometheus sample formatting: integers render bare, +Inf as
-    ``+Inf``."""
-    if v == math.inf:
-        return "+Inf"
-    if isinstance(v, float) and v.is_integer():
-        return str(int(v))
-    return repr(float(v)) if isinstance(v, float) else str(v)
 
 
 class _Metric:
@@ -337,31 +316,3 @@ class MetricsRegistry:
 
     def to_json(self, indent=2) -> str:
         return json.dumps(self.snapshot(), indent=indent)
-
-    def render_prometheus(self) -> str:
-        """Prometheus text exposition format 0.0.4: ``# HELP`` /
-        ``# TYPE`` headers, escaped label values, and per-histogram
-        ``_bucket``/``_sum``/``_count`` sample families."""
-        lines = []
-        for name in sorted(self._metrics):
-            m = self._metrics[name]
-            if m.help:
-                lines.append(f"# HELP {name} {_escape_help(m.help)}")
-            lines.append(f"# TYPE {name} {m.kind}")
-            for labels, child in m.series():
-                base = ",".join(
-                    f'{k}="{_escape_label_value(v)}"'
-                    for k, v in labels.items())
-                if m.kind == "histogram":
-                    for le, c in child.cumulative():
-                        lab = (base + "," if base else "") + \
-                            f'le="{_fmt(float(le))}"'
-                        lines.append(f"{name}_bucket{{{lab}}} {c}")
-                    brace = f"{{{base}}}" if base else ""
-                    lines.append(f"{name}_sum{brace} {_fmt(child.sum)}")
-                    lines.append(
-                        f"{name}_count{brace} {child.count}")
-                else:
-                    brace = f"{{{base}}}" if base else ""
-                    lines.append(f"{name}{brace} {_fmt(child.value)}")
-        return "\n".join(lines) + "\n"

@@ -1,22 +1,28 @@
-"""Structured span/event tracer emitting Chrome-trace / Perfetto
-compatible JSON (the ``traceEvents`` array format: complete events
-``ph="X"`` with microsecond ``ts``/``dur``, instant events ``ph="i"``).
+"""Structured span/event tracer.
 
-Spans use the monotonic clock (``time.perf_counter_ns``) so a wall-clock
-adjustment mid-run can never produce negative durations.  JAX dispatch
-is asynchronous — a jitted call returns before the device work finishes
-— so a span that should *contain* device work must fence on its outputs
-before closing:
+Every span is a ``jax.profiler.TraceAnnotation`` (a TraceMe event),
+whether or not the tracer is ``enabled``: whenever a profiler is
+recording (``jax.profiler.trace``, ``start_trace``, TensorBoard
+capture), the span lands in the trace's host plane on the same clock as
+the device ops, with its args as the event's metadata.  With no
+profiler recording, a span of a disabled tracer costs a microsecond or
+two:
 
-    with tracer.span("decode_step", {"tokens": n}) as sp:
-        out, cache, flag, keys = jitted_step(...)
-        sp.fence(out, flag)          # block_until_ready at span exit
+    with tracer.span("serve.decode.dispatch", {"rows": n}):
+        out, cache, flag, keys = jitted_step(...)    # returns at once
+    with tracer.span("serve.decode.wait", {"what": "flag"}):
+        faulted = bool(flag)                         # blocks on the chip
 
-Fencing happens only when the tracer is enabled; a disabled tracer hands
-out a shared no-op span, so instrumented hot paths cost one attribute
-check when tracing is off and the engine's token streams are
-byte-identical either way (fencing orders host timestamps, never
-values).
+A span marks a host phase and never syncs the device: JAX dispatch is
+asynchronous, so device work shows as the time the host spends in the
+spans that block on a readback the program makes anyway.
+
+``enabled`` controls only the in-memory record: Chrome-trace / Perfetto
+JSON (the ``traceEvents`` array format: complete events ``ph="X"`` with
+microsecond ``ts``/``dur``, instant events ``ph="i"``) on the
+tracer's own monotonic clock (``time.perf_counter_ns`` from the
+tracer's creation), with the same span names.  Instants go to the
+record only.
 
 Event volume is bounded (``max_events``): once full, new events are
 counted in ``dropped`` instead of growing an unbounded list inside a
@@ -36,64 +42,55 @@ from __future__ import annotations
 import json
 import time
 
-
-class _NullSpan:
-    """Shared do-nothing span for disabled tracers."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def fence(self, *values):
-        pass
-
-    def set_args(self, **kv):
-        pass
+# jax.profiler.TraceAnnotation, bound on first use so that repro.obs
+# stays importable without jax
+_annotation = None
 
 
-_NULL_SPAN = _NullSpan()
+def _trace_me(name: str, args: dict):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
 
 
 class Span:
-    __slots__ = ("tracer", "name", "args", "_t0", "_fence")
+    """One host phase: a TraceMe event, and a complete event in the
+    tracer's record when it is enabled."""
+
+    __slots__ = ("tracer", "name", "args", "_t0", "_me")
 
     def __init__(self, tracer, name, args):
         self.tracer = tracer
         self.name = name
         self.args = dict(args) if args else {}
         self._t0 = None
-        self._fence = ()
-
-    def fence(self, *values):
-        """Values to ``jax.block_until_ready`` before the span closes,
-        attributing their device work to this span."""
-        self._fence = values
+        self._me = None
 
     def set_args(self, **kv):
+        """Add args known only inside the span (counts, outcomes)."""
         self.args.update(kv)
+        if self._me is not None:
+            self._me.set_metadata(**kv)
 
     def __enter__(self):
-        self._t0 = self.tracer._now_us()
+        self._me = _trace_me(self.name, self.args)
+        self._me.__enter__()
+        if self.tracer.enabled:
+            self._t0 = self.tracer._now_us()
         return self
 
     def __exit__(self, *exc):
-        if self._fence:
-            # local import: obs stays importable without jax (metrics/
-            # faultrate are pure-stdlib); fencing is only reachable from
-            # engine code that already runs under jax
-            import jax
-
-            jax.block_until_ready(self._fence)
-        t1 = self.tracer._now_us()
-        self.tracer._emit({
-            "name": self.name, "ph": "X", "ts": self._t0,
-            "dur": max(0.0, t1 - self._t0), "pid": self.tracer.pid,
-            "tid": self.tracer.tid, "args": self.args,
-        })
+        if self.tracer.enabled:
+            t1 = self.tracer._now_us()
+            self.tracer._emit({
+                "name": self.name, "ph": "X", "ts": self._t0,
+                "dur": max(0.0, t1 - self._t0), "pid": self.tracer.pid,
+                "tid": self.tracer.tid, "args": self.args,
+            })
+        self._me.__exit__(*exc)
         return False
 
 
@@ -122,10 +119,9 @@ class Tracer:
         if self.sink is not None:
             self.sink(ev)
 
-    def span(self, name: str, args: dict | None = None):
-        """Context manager recording a complete event around its body."""
-        if not self.enabled:
-            return _NULL_SPAN
+    def span(self, name: str, args: dict | None = None) -> Span:
+        """Context manager around one host phase: a TraceMe event always,
+        and a complete event in the record when enabled."""
         return Span(self, name, args)
 
     def instant(self, name: str, args: dict | None = None) -> None:

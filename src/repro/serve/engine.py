@@ -210,18 +210,43 @@ metrics registry after each ``admit()``/``step()`` (monotonic
 ``inc_to`` — the exported counters equal the stats fields exactly, by
 construction), per-step deltas feed the rolling ``FaultRateMonitor``
 (the observed detection/retry-rate surface ROADMAP 5b's adaptive
-protection consumes), and — when tracing is enabled — the scheduler's
-phases are recorded as Chrome-trace spans (``admit``, ``prefill``,
-``prefill_chunk``, ``decode_step``, ``abft_check``, ``abft_retry``,
-``cow_copy``) fenced with ``jax.block_until_ready`` so asynchronous
-device work is attributed to the right span, plus instant events for
-fault detections, evictions/rejections, intensity-guided
-``scheme_flip``s carrying {intensity, scheme, decode, prefill,
-model_parallel}, and one ``plan_row`` instant per protection-plan entry
-at attach time (the per-shard plan surface).  Telemetry is passive:
-greedy token streams are byte-identical with it enabled or disabled
-(fencing orders host timestamps, never values), and with no telemetry
-attached the instrumented paths reduce to no-op spans.
+protection consumes), and — when tracing is enabled — the spans below
+are recorded as Chrome-trace JSON, plus instant events for fault
+detections, evictions/rejections, intensity-guided ``scheme_flip``s
+carrying {intensity, scheme, decode, prefill, model_parallel}, and one
+``plan_row`` instant per protection-plan entry at attach time (the
+per-shard plan surface).
+
+Spans (repro/obs/trace.py) are host phases.  Every engine opens them,
+telemetry or not, and they land in any ``jax.profiler`` trace on the
+device's clock; an enabled tracer also records them, under the same
+names, on its own clock.  No span syncs the device: device work shows
+as time in the ``wait`` spans, the readbacks the engine makes anyway.
+
+  serve.step {decode, prefill}       one step() (tokens it served)
+    serve.schedule                   adaptation, fault poll, chunk
+                                     budget and plan, paged growth
+    serve.cow_copy {pairs}           copy-on-write block moves
+    serve.<call> {rows, shape, uid}  call in decode | chunk | verify
+      serve.<call>.prepare           host arrays, host->device copies
+                                     (fault operands too), table and
+                                     key gathers
+      serve.<call>.dispatch          the jitted call until it returns
+      serve.<call>.wait {what}       blocking readback: flag | tokens
+      serve.retry {call}             a detected fault's re-execution
+                                     (dispatch + flag wait)
+      serve.<call>.commit            cache/key rebinds, token stamps,
+                                     request and slot bookkeeping
+    serve.account                    selection record, telemetry sync
+  serve.admit {consumed, admitted}   one admit() (request counts)
+    serve.schedule                   admission screening
+    serve.prefill {rows, shape, uid} whole-prompt prefill (unchunked),
+                                     with the same children
+    serve.account
+
+``uid`` is the first row's request; ``shape`` is the padded
+rows x tokens of the call.  Telemetry is passive: greedy token streams
+are byte-identical with it enabled or disabled.
 """
 
 from __future__ import annotations
@@ -267,9 +292,9 @@ __all__ = [
     "ChunkCursor", "PRE_PREFILL_ERRORS",
 ]
 
-# shared no-op tracer for engines without telemetry: instrumented paths
-# cost one disabled-flag check, and hand out a singleton null span
-_NULL_TRACER = Tracer(enabled=False)
+# tracer of engines without telemetry: its spans reach a recording
+# jax.profiler trace, and it keeps no in-memory record
+_DEFAULT_TRACER = Tracer(enabled=False)
 
 
 def _pytrees_equal(a, b) -> bool:
@@ -364,7 +389,7 @@ class ServeEngine:
             telemetry = EngineTelemetry()
         self.telemetry = telemetry
         self._tr = telemetry.tracer if telemetry is not None \
-            else _NULL_TRACER
+            else _DEFAULT_TRACER
         self._last_scheme: str | None = None
         # compiled protection plan for this (model, hardware, serving,
         # shard) tuple: per-device GEMM shapes under the executor's
@@ -564,7 +589,7 @@ class ServeEngine:
         object must be fresh too (counter mirroring is monotonic)."""
         self.telemetry = telemetry
         self._tr = telemetry.tracer if telemetry is not None \
-            else _NULL_TRACER
+            else _DEFAULT_TRACER
         self.scheduler.tracer = self._tr
         self._emit_plan_rows()
 
@@ -725,11 +750,10 @@ class ServeEngine:
         (plain data movement, not an ABFT-protected GEMM)."""
         if not cow_pairs:
             return
-        with self._tr.span("cow_copy", {"pairs": len(cow_pairs)}) as sp:
+        with self._tr.span("serve.cow_copy", {"pairs": len(cow_pairs)}):
             self.cache = self.model.copy_paged_blocks(
                 self.cache, [s for s, _ in cow_pairs],
                 [d for _, d in cow_pairs])
-            sp.fence(self.cache)
         self.stats.cow_copies += len(cow_pairs)
 
     def admit(self, pending: list, fault: ModelFault | None = None,
@@ -742,17 +766,19 @@ class ServeEngine:
         past a transiently-deferred head (see module docstring).
         ``fault``/``fault_uid``: campaign injection applied only when the
         targeted request actually reaches prefill."""
-        with self._tr.span("admit") as sp:
+        with self._tr.span("serve.admit") as sp:
             consumed = self._admit_impl(pending, fault, fault_uid)
             sp.set_args(consumed=len(consumed),
                         admitted=len([r for r in consumed
                                       if r.error is None]))
-        self._sync_telemetry()
+            with self._tr.span("serve.account"):
+                self._sync_telemetry()
         return consumed
 
     def _admit_impl(self, pending: list, fault: ModelFault | None = None,
                     fault_uid: int | None = None) -> list:
-        batch = self.scheduler.select_admission(pending)
+        with self._tr.span("serve.schedule"):
+            batch = self.scheduler.select_admission(pending)
         admitted, slot_list = batch.admitted, batch.slot_list
         if not admitted:
             return batch.consumed
@@ -772,112 +798,127 @@ class ServeEngine:
                 self._pending_prefill_fault = (fault_uid, fault)
             return batch.consumed
 
-        slot_ids = np.asarray(slot_list, np.int32)
-        full_lens = np.asarray([len(r.prompt) for r in admitted], np.int32)
-        prefix = np.asarray(
-            [p.match_len if p is not None else 0
-             for p in batch.prefix_plans], np.int32)
-        lengths = full_lens - prefix         # valid SUFFIX tokens per row
-        # admissible prompts always fit (budget check above), so clamping
-        # the bucketed pad to max_len keeps the scatter in bounds
-        Lpad = min(_pad_len(int(lengths.max())), self.max_len)
-        toks = np.zeros((len(admitted), Lpad), np.int32)
-        for i, r in enumerate(admitted):
-            toks[i, : lengths[i]] = r.prompt[prefix[i]:]
+        tr = self._tr
+        with tr.span("serve.prefill", {"rows": len(admitted),
+                                       "uid": admitted[0].uid}) as call:
+            with tr.span("serve.prefill.prepare"):
+                slot_ids = np.asarray(slot_list, np.int32)
+                full_lens = np.asarray([len(r.prompt) for r in admitted],
+                                       np.int32)
+                prefix = np.asarray(
+                    [p.match_len if p is not None else 0
+                     for p in batch.prefix_plans], np.int32)
+                lengths = full_lens - prefix   # valid SUFFIX tokens per row
+                # admissible prompts always fit (budget check above), so
+                # clamping the bucketed pad to max_len keeps the scatter
+                # in bounds
+                Lpad = min(_pad_len(int(lengths.max())), self.max_len)
+                toks = np.zeros((len(admitted), Lpad), np.int32)
+                for i, r in enumerate(admitted):
+                    toks[i, : lengths[i]] = r.prompt[prefix[i]:]
 
-        # COW payload moves are committed BEFORE the attempt so the
-        # detect->retry window sees stable tables and block contents
-        self._copy_cow_blocks(batch.cow_pairs)
+                # COW payload moves are committed BEFORE the attempt so
+                # the detect->retry window sees stable tables and block
+                # contents
+                self._copy_cow_blocks(batch.cow_pairs)
 
-        tables = (self.pool.device_tables(slot_ids)
-                  if self.pool is not None else None)
-        keys = self.keys[jnp.asarray(slot_ids)]
-        use_prefix = bool(prefix.any())
-        args = (self.params, jnp.asarray(toks), jnp.asarray(slot_ids),
-                jnp.asarray(lengths))
-        prefix_dev = jnp.asarray(prefix)
-        prev_cache = self.cache        # pre-admission state, kept for retry
+                tables = (self.pool.device_tables(slot_ids)
+                          if self.pool is not None else None)
+                keys = self.keys[jnp.asarray(slot_ids)]
+                use_prefix = bool(prefix.any())
+                args = (self.params, jnp.asarray(toks),
+                        jnp.asarray(slot_ids), jnp.asarray(lengths))
+                prefix_dev = jnp.asarray(prefix)
+                prev_cache = self.cache  # pre-admission state, for retry
+                f = fault if fault is not None else ModelFault.none()
+                meta = self._take_injection_meta("admit_fault") \
+                    if fault is not None else None
+            call.set_args(shape=f"{len(admitted)}x{Lpad}")
 
-        def attempt(fa):
-            if use_prefix:
-                return self._prefill_prefix(
+            def attempt(fa):
+                if use_prefix:
+                    return self._prefill_prefix(
+                        args[0], args[1], prev_cache, args[2], args[3],
+                        keys, tables, prefix_dev, fa)
+                return self._prefill(
                     args[0], args[1], prev_cache, args[2], args[3], keys,
-                    tables, prefix_dev, fa)
-            return self._prefill(
-                args[0], args[1], prev_cache, args[2], args[3], keys,
-                tables, fa)
+                    tables, fa)
 
-        f = fault if fault is not None else ModelFault.none()
-        meta = self._take_injection_meta("admit_fault") \
-            if fault is not None else None
-        with self._tr.span("prefill", {"rows": len(admitted),
-                                       "tokens": int(lengths.sum())}) as sp:
-            first, new_cache, flag, nkeys = attempt(f)
-            sp.fence(first, flag)
-        with self._tr.span("abft_check", {"phase": "prefill"}):
-            faulted = bool(flag)
-        if faulted:
-            self.stats.faults_detected += 1
-            self._tr.instant("fault_detected", {"phase": "prefill"})
-            for _ in range(self.policy.max_retries):
-                self.stats.retries += 1
-                # clean retry from the PRE-admission cache — never from the
-                # possibly-corrupted attempt (mirrors decode's prev_cache);
-                # same keys, so the retry resamples the same token
-                with self._tr.span("abft_retry",
-                                   {"phase": "prefill"}) as sp:
-                    first, new_cache, flag, nkeys = attempt(
-                        ModelFault.none())
-                    sp.fence(first, flag)
-                if not bool(flag):
-                    break
-            if meta is not None:
-                self._record_injection(
-                    meta, "prefill",
-                    "uncorrected" if bool(flag) else "corrected")
-            if bool(flag):
-                # persistent fault: evict the admission batch with recorded
-                # errors instead of retrying it forever (livelock fix).
-                # _release drops refcounts only — a shared prefix block a
-                # LIVE request still references stays resident
-                self.stats.hard_faults += 1
-                self._tr.instant("hard_fault", {"phase": "prefill"})
-                for slot, r in zip(slot_ids, admitted):
-                    self._finish(r, "hard_fault:prefill", evict=True)
-                    self._release(int(slot))
-                return batch.consumed
-        elif meta is not None:
-            outcome, extra = ("undetected", {})
-            if self.classify_injections:
-                s_first, s_cache, _, _ = attempt(ModelFault.none())
-                outcome, extra = self._shadow_outcome(
-                    first, new_cache, (s_first, s_cache))
-            self._record_injection(meta, "prefill", outcome, **extra)
+            with tr.span("serve.prefill.dispatch"):
+                first, new_cache, flag, nkeys = attempt(f)
+            with tr.span("serve.prefill.wait", {"what": "flag"}):
+                faulted = bool(flag)
+            if faulted:
+                self.stats.faults_detected += 1
+                tr.instant("fault_detected", {"phase": "prefill"})
+                for _ in range(self.policy.max_retries):
+                    self.stats.retries += 1
+                    # clean retry from the PRE-admission cache — never
+                    # from the possibly-corrupted attempt (mirrors
+                    # decode's prev_cache); same keys, so the retry
+                    # resamples the same token
+                    with tr.span("serve.retry", {"call": "prefill"}):
+                        with tr.span("serve.prefill.dispatch"):
+                            first, new_cache, flag, nkeys = attempt(
+                                ModelFault.none())
+                        with tr.span("serve.prefill.wait",
+                                     {"what": "flag"}):
+                            faulted = bool(flag)
+                    if not faulted:
+                        break
+                if meta is not None:
+                    self._record_injection(
+                        meta, "prefill",
+                        "uncorrected" if faulted else "corrected")
+                if faulted:
+                    # persistent fault: evict the admission batch with
+                    # recorded errors instead of retrying it forever
+                    # (livelock fix).  _release drops refcounts only — a
+                    # shared prefix block a LIVE request still references
+                    # stays resident
+                    self.stats.hard_faults += 1
+                    tr.instant("hard_fault", {"phase": "prefill"})
+                    for slot, r in zip(slot_ids, admitted):
+                        self._finish(r, "hard_fault:prefill", evict=True)
+                        self._release(int(slot))
+                    return batch.consumed
+            elif meta is not None:
+                outcome, extra = ("undetected", {})
+                if self.classify_injections:
+                    s_first, s_cache, _, _ = attempt(ModelFault.none())
+                    outcome, extra = self._shadow_outcome(
+                        first, new_cache, (s_first, s_cache))
+                self._record_injection(meta, "prefill", outcome, **extra)
 
-        self.cache = new_cache
-        self.keys = self.keys.at[jnp.asarray(slot_ids)].set(nkeys)
-        # admit-time monolithic prefill is a prefill-only "step" in the
-        # selection trace: the whole-prompt token mass lands in one call
-        # (exactly the composition the chunked scheduler bounds)
-        self._observe_step_mix(0, int(lengths.sum()))
-        first = np.asarray(first)
-        now = time.perf_counter()
-        for i, (slot, req) in enumerate(zip(slot_ids, admitted)):
-            req.generated.append(int(first[i]))
-            req.times.append(now)
-            self.stats.tokens += 1
-            self.stats.prompt_tokens_total += int(full_lens[i])
-            self.stats.prefix_tokens_shared += int(prefix[i])
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(req)           # budget met at prefill: the
-                self._release(int(slot))    # request never occupies a slot
-                continue
-            self.active[int(slot)] = req
-            self.pos[int(slot)] = int(full_lens[i])
-            if self.index is not None:
-                # register only AFTER the flag read back clean: the index
-                # must never name blocks holding a faulty attempt's data
-                self.index.add(req.prompt, self.pool.tables[int(slot)])
+            with tr.span("serve.prefill.wait", {"what": "tokens"}):
+                first = np.asarray(first)
+            with tr.span("serve.prefill.commit"):
+                self.cache = new_cache
+                self.keys = self.keys.at[jnp.asarray(slot_ids)].set(nkeys)
+                # admit-time monolithic prefill is a prefill-only "step"
+                # in the selection trace: the whole-prompt token mass
+                # lands in one call (exactly the composition the chunked
+                # scheduler bounds)
+                self._observe_step_mix(0, int(lengths.sum()))
+                now = time.perf_counter()
+                for i, (slot, req) in enumerate(zip(slot_ids, admitted)):
+                    req.generated.append(int(first[i]))
+                    req.times.append(now)
+                    self.stats.tokens += 1
+                    self.stats.prompt_tokens_total += int(full_lens[i])
+                    self.stats.prefix_tokens_shared += int(prefix[i])
+                    if len(req.generated) >= req.max_new_tokens:
+                        self._finish(req)         # budget met at prefill:
+                        self._release(int(slot))  # the request never
+                        continue                  # occupies a slot
+                    self.active[int(slot)] = req
+                    self.pos[int(slot)] = int(full_lens[i])
+                    if self.index is not None:
+                        # register only AFTER the flag read back clean:
+                        # the index must never name blocks holding a
+                        # faulty attempt's data
+                        self.index.add(req.prompt,
+                                       self.pool.tables[int(slot)])
         return batch.consumed
 
     # ------------------------------------------------------------ decoding
@@ -897,27 +938,45 @@ class ServeEngine:
         level from the observed fault rates BEFORE the step executes."""
         before = self.stats.steps
         t0 = time.perf_counter()
-        self._maybe_adapt()
-        if fault is None and self.fault_model is not None:
-            ev = self.fault_model.poll()
-            if ev is not None:
-                fault = ev.model_fault
-                self._injection_meta = {"source": "campaign",
-                                        **ev.describe()}
-        if self.chunk_tokens is not None:
-            out = self._step_chunked(fault)
-        else:
-            out = self._serve_core(fault)
-            if self.stats.steps > before:
-                self._observe_step_mix(self._last_decode_tokens, 0)
-        # a fault that found no executing call this step (idle engine)
-        # corrupted nothing — drop its unclaimed metadata
-        self._injection_meta = None
-        if self.telemetry is not None:
-            if self.stats.steps > before:
-                self.telemetry.observe_step_latency(
-                    time.perf_counter() - t0)
-            self._sync_telemetry()
+        tr = self._tr
+        with tr.span("serve.step") as sp:
+            with tr.span("serve.schedule"):
+                self._maybe_adapt()
+                if fault is None and self.fault_model is not None:
+                    ev = self.fault_model.poll()
+                    if ev is not None:
+                        fault = ev.model_fault
+                        self._injection_meta = {"source": "campaign",
+                                                **ev.describe()}
+                rows = self._plan_chunks() \
+                    if self.chunk_tokens is not None else None
+                # paged growth/COW guard BEFORE the jitted decode (tables
+                # stable across the attempt/retry window); a verify step
+                # grows for its draft window once the drafts exist
+                cow_pairs = self.scheduler.grow_for_decode() \
+                    if self.spec is None else []
+            # the COW payload moves the guard planned are committed on
+            # device before the first call
+            self._copy_cow_blocks(cow_pairs)
+            prefill_tokens = 0
+            if rows is not None:
+                out, prefill_tokens = self._step_chunked(rows, fault)
+            else:
+                out = self._serve_core(fault)
+            # a fault that found no executing call this step (idle
+            # engine) corrupted nothing — drop its unclaimed metadata
+            self._injection_meta = None
+            with tr.span("serve.account"):
+                if self.stats.steps > before:
+                    self._observe_step_mix(self._last_decode_tokens,
+                                           prefill_tokens)
+                if self.telemetry is not None:
+                    if self.stats.steps > before:
+                        self.telemetry.observe_step_latency(
+                            time.perf_counter() - t0)
+                    self._sync_telemetry()
+            sp.set_args(decode=self._last_decode_tokens,
+                        prefill=prefill_tokens)
         return out
 
     def _observe_step_mix(self, decode_tokens: int,
@@ -961,21 +1020,24 @@ class ServeEngine:
             self.chunk_tokens = budget
             self.stats.chunk_budget_retunes += 1
 
-    def _plan_chunks(self, budget: int) -> list:
-        return self.scheduler.plan_chunks(budget)
-
-    def _step_chunked(self, fault: ModelFault | None = None) -> dict:
-        """One budgeted mixed step: decode tokens are packed first (every
-        resident stream advances every step — the starvation guarantee),
-        then prefill chunks fill ``chunk_tokens - n_decode``.  An injected
-        step fault lands on the prefill chunk when one is scheduled, else
-        on the decode call — each call retries independently, so a chunk
-        fault re-executes ONLY that chunk."""
+    def _plan_chunks(self) -> list:
+        """This step's prefill chunks under the token budget left after
+        the resident decode tokens (re-tuning an auto budget first)."""
         if self.chunk_auto:
             self._retune_chunk_budget()
-        n_decode = len(self.active)
-        rows = self.scheduler.plan_chunks(
-            max(0, self.chunk_tokens - n_decode))
+        return self.scheduler.plan_chunks(
+            max(0, self.chunk_tokens - len(self.active)))
+
+    def _step_chunked(self, rows: list,
+                      fault: ModelFault | None = None) -> tuple:
+        """One budgeted mixed step over the planned chunk ``rows``:
+        decode tokens are packed first (every resident stream advances
+        every step — the starvation guarantee), then the prefill chunks
+        fill ``chunk_tokens - n_decode``.  An injected step fault lands
+        on the prefill chunk when one is scheduled, else on the decode
+        call — each call retries independently, so a chunk fault
+        re-executes ONLY that chunk.  Returns ({uid: token}, prefill
+        tokens served)."""
         prefill_tokens = sum(take for _, _, take, _ in rows)
         chunk_fault = fault if rows else None
         decode_fault = fault if not rows else None
@@ -995,10 +1057,7 @@ class ServeEngine:
                 # the step so run()'s fault_at disarm check sees it and
                 # never re-injects a fault this chunk already consumed
                 self.stats.steps += 1
-        if self.stats.steps > steps_before:
-            self._observe_step_mix(self._last_decode_tokens,
-                                   prefill_tokens)
-        return out
+        return out, prefill_tokens
 
     def _run_prefill_chunk(self, rows: list,
                            fault: ModelFault | None) -> bool:
@@ -1028,236 +1087,249 @@ class ServeEngine:
             meta = self._take_injection_meta(
                 "admit_fault" if pending_src else "manual")
 
+        tr = self._tr
         Apad = _pad_rows(A, self.slots)
         Lpad = min(_pad_len(max(take for _, _, take, _ in rows)),
                    self.max_len)
-        toks = np.zeros((Apad, Lpad), np.int32)
-        slot_ids = np.full((Apad,), slot_list[0], np.int32)
-        lengths = np.zeros((Apad,), np.int32)
-        starts = np.zeros((Apad,), np.int32)
-        final = np.zeros((Apad,), bool)
-        for i, (slot, cur, take, fin) in enumerate(rows):
-            toks[i, :take] = cur.req.prompt[cur.filled:cur.filled + take]
-            slot_ids[i] = slot
-            lengths[i] = take
-            starts[i] = cur.filled
-            final[i] = fin
-        # padding rows alias row 0's slot with lengths == 0: their cache
-        # writes route to the drop sentinel and their sampled token / key
-        # advance are masked by ``final`` — pure shape ballast so the jit
-        # cache is keyed by (row bucket, length bucket) only
+        with tr.span("serve.chunk", {"rows": A, "shape": f"{Apad}x{Lpad}",
+                                     "uid": rows[0][1].req.uid}):
+            with tr.span("serve.chunk.prepare"):
+                toks = np.zeros((Apad, Lpad), np.int32)
+                slot_ids = np.full((Apad,), slot_list[0], np.int32)
+                lengths = np.zeros((Apad,), np.int32)
+                starts = np.zeros((Apad,), np.int32)
+                final = np.zeros((Apad,), bool)
+                for i, (slot, cur, take, fin) in enumerate(rows):
+                    toks[i, :take] = cur.req.prompt[
+                        cur.filled:cur.filled + take]
+                    slot_ids[i] = slot
+                    lengths[i] = take
+                    starts[i] = cur.filled
+                    final[i] = fin
+                # padding rows alias row 0's slot with lengths == 0: their
+                # cache writes route to the drop sentinel and their
+                # sampled token / key advance are masked by ``final`` —
+                # pure shape ballast so the jit cache is keyed by (row
+                # bucket, length bucket) only
+                tables = (self.pool.device_tables(slot_ids)
+                          if self.pool is not None else None)
+                keys = self.keys[jnp.asarray(slot_ids)]
+                prev_cache = self.cache    # pre-chunk state, for retry
+                args = (self.params, jnp.asarray(toks),
+                        jnp.asarray(slot_ids), jnp.asarray(lengths),
+                        jnp.asarray(starts), jnp.asarray(final))
+                f = fault if fault is not None else ModelFault.none()
+                retry_f = f if (meta is not None
+                                and meta.get("kind") == "permanent") \
+                    else ModelFault.none()
 
-        tables = (self.pool.device_tables(slot_ids)
-                  if self.pool is not None else None)
-        keys = self.keys[jnp.asarray(slot_ids)]
-        prev_cache = self.cache        # pre-chunk state, kept for retry
-        args = (self.params, jnp.asarray(toks), jnp.asarray(slot_ids),
-                jnp.asarray(lengths), jnp.asarray(starts),
-                jnp.asarray(final))
+            def attempt(fa):
+                return self._prefill_chunk(
+                    args[0], args[1], prev_cache, args[2], args[3], keys,
+                    tables, args[4], args[5], fa)
 
-        def attempt(fa):
-            return self._prefill_chunk(
-                args[0], args[1], prev_cache, args[2], args[3], keys,
-                tables, args[4], args[5], fa)
+            with tr.span("serve.chunk.dispatch"):
+                first, new_cache, flag, nkeys = attempt(f)
+            with tr.span("serve.chunk.wait", {"what": "flag"}):
+                faulted = bool(flag)
+            if faulted:
+                self.stats.faults_detected += 1
+                tr.instant("fault_detected", {"phase": "prefill_chunk"})
+                for _ in range(self.policy.max_retries):
+                    self.stats.retries += 1
+                    self.stats.chunk_retries += 1
+                    with tr.span("serve.retry", {"call": "chunk"}):
+                        with tr.span("serve.chunk.dispatch"):
+                            first, new_cache, flag, nkeys = attempt(
+                                retry_f)
+                        with tr.span("serve.chunk.wait", {"what": "flag"}):
+                            faulted = bool(flag)
+                    if not faulted:
+                        break
+                if meta is not None:
+                    self._record_injection(
+                        meta, "prefill_chunk",
+                        "uncorrected" if faulted else "corrected")
+                if faulted:
+                    # persistent chunk fault: evict ONLY this chunk
+                    # batch's requests (their earlier chunks die with
+                    # their blocks — refcounts protect any shared prefix
+                    # a live sharer holds); the committed cache stays
+                    # pre-chunk
+                    self.stats.hard_faults += 1
+                    tr.instant("hard_fault", {"phase": "prefill_chunk"})
+                    for slot, cur, _, _ in rows:
+                        self._finish(cur.req, "hard_fault:prefill",
+                                     evict=True)
+                        del self._prefill_cursors[slot]
+                        self._release(slot)
+                        if self._pending_prefill_fault is not None and \
+                                self._pending_prefill_fault[0] == \
+                                cur.req.uid:
+                            self._pending_prefill_fault = None  # gone
+                    return False
+            elif meta is not None:
+                outcome, extra = ("undetected", {})
+                if self.classify_injections:
+                    s_first, s_cache, _, _ = attempt(ModelFault.none())
+                    outcome, extra = self._shadow_outcome(
+                        first, new_cache, (s_first, s_cache))
+                self._record_injection(meta, "prefill_chunk", outcome,
+                                       **extra)
 
-        f = fault if fault is not None else ModelFault.none()
-        retry_f = f if (meta is not None
-                        and meta.get("kind") == "permanent") \
-            else ModelFault.none()
-        with self._tr.span(
-                "prefill_chunk",
-                {"rows": A,
-                 "tokens": int(sum(t for _, _, t, _ in rows))}) as sp:
-            first, new_cache, flag, nkeys = attempt(f)
-            sp.fence(first, flag)
-        with self._tr.span("abft_check", {"phase": "prefill_chunk"}):
-            faulted = bool(flag)
-        if faulted:
-            self.stats.faults_detected += 1
-            self._tr.instant("fault_detected", {"phase": "prefill_chunk"})
-            for _ in range(self.policy.max_retries):
-                self.stats.retries += 1
-                self.stats.chunk_retries += 1
-                with self._tr.span("abft_retry",
-                                   {"phase": "prefill_chunk"}) as sp:
-                    first, new_cache, flag, nkeys = attempt(retry_f)
-                    sp.fence(first, flag)
-                if not bool(flag):
-                    break
-            if meta is not None:
-                self._record_injection(
-                    meta, "prefill_chunk",
-                    "uncorrected" if bool(flag) else "corrected")
-            if bool(flag):
-                # persistent chunk fault: evict ONLY this chunk batch's
-                # requests (their earlier chunks die with their blocks —
-                # refcounts protect any shared prefix a live sharer
-                # holds); the committed cache stays pre-chunk
-                self.stats.hard_faults += 1
-                self._tr.instant("hard_fault",
-                                 {"phase": "prefill_chunk"})
-                for slot, cur, _, _ in rows:
-                    self._finish(cur.req, "hard_fault:prefill", evict=True)
+            with tr.span("serve.chunk.wait", {"what": "tokens"}):
+                first = np.asarray(first)
+            with tr.span("serve.chunk.commit"):
+                self.cache = new_cache
+                self.keys = self.keys.at[jnp.asarray(slot_list)].set(
+                    jnp.asarray(nkeys)[:A])
+                self.stats.prefill_chunks += A
+                now = time.perf_counter()
+                for i, (slot, cur, take, fin) in enumerate(rows):
+                    cur.filled += take
+                    self.pos[slot] = cur.filled
+                    if not fin:
+                        continue
+                    req = cur.req
+                    req.generated.append(int(first[i]))
+                    req.times.append(now)
+                    self.stats.tokens += 1
+                    self.stats.prompt_tokens_total += cur.total
+                    self.stats.prefix_tokens_shared += cur.prefix
                     del self._prefill_cursors[slot]
-                    self._release(slot)
-                    if self._pending_prefill_fault is not None and \
-                            self._pending_prefill_fault[0] == cur.req.uid:
-                        self._pending_prefill_fault = None  # target gone
-                return False
-        elif meta is not None:
-            outcome, extra = ("undetected", {})
-            if self.classify_injections:
-                s_first, s_cache, _, _ = attempt(ModelFault.none())
-                outcome, extra = self._shadow_outcome(
-                    first, new_cache, (s_first, s_cache))
-            self._record_injection(meta, "prefill_chunk", outcome,
-                                   **extra)
-
-        self.cache = new_cache
-        self.keys = self.keys.at[jnp.asarray(slot_list)].set(
-            jnp.asarray(nkeys)[:A])
-        self.stats.prefill_chunks += A
-        first = np.asarray(first)
-        now = time.perf_counter()
-        for i, (slot, cur, take, fin) in enumerate(rows):
-            cur.filled += take
-            self.pos[slot] = cur.filled
-            if not fin:
-                continue
-            req = cur.req
-            req.generated.append(int(first[i]))
-            req.times.append(now)
-            self.stats.tokens += 1
-            self.stats.prompt_tokens_total += cur.total
-            self.stats.prefix_tokens_shared += cur.prefix
-            del self._prefill_cursors[slot]
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(req)          # budget met at prefill
-                self._release(slot)
-                continue
-            self.active[slot] = req
-            if self.index is not None:
-                self.index.add(req.prompt, self.pool.tables[slot])
+                    if len(req.generated) >= req.max_new_tokens:
+                        self._finish(req)          # budget met at prefill
+                        self._release(slot)
+                        continue
+                    self.active[slot] = req
+                    if self.index is not None:
+                        self.index.add(req.prompt, self.pool.tables[slot])
         return True
 
     def _decode_core(self, fault: ModelFault | None = None) -> dict:
-        """One decode step for all active slots.  Returns {uid: token}."""
-        # paged growth/COW guard runs on the scheduler BEFORE the jitted
-        # step (tables stable across the attempt/retry window); the COW
-        # payload moves it plans are committed here on device
-        self._copy_cow_blocks(self.scheduler.grow_for_decode())
+        """One decode step for all active slots (``step`` has run the
+        paged growth guard).  Returns {uid: token}."""
         if not self.active:
             return {}
-        toks = np.zeros((self.slots, 1), np.int32)
-        mask = np.zeros((self.slots,), bool)
-        for s, req in self.active.items():
-            toks[s, 0] = req.generated[-1]
-            mask[s] = True
-        pos = jnp.asarray(self.pos)            # (slots,) vectorized cursor
-        tables = (self.pool.device_tables()
-                  if self.pool is not None else None)
-        f = fault if fault is not None else ModelFault.none()
-        meta = self._take_injection_meta("manual") \
-            if fault is not None else None
-        # a sticky permanent fault models a faulty UNIT: it corrupts the
-        # retry exactly like the attempt (retry cannot clear it — the
-        # detect->recompute loop's transient-fault assumption breaks,
-        # which is the 2205.12177 detection gap this campaign mode
-        # exercises); transient/manual faults retry clean as before
-        retry_f = f if (meta is not None
-                        and meta.get("kind") == "permanent") \
-            else ModelFault.none()
+        tr = self._tr
+        with tr.span("serve.decode", {"rows": len(self.active),
+                                      "shape": f"{self.slots}x1"}):
+            with tr.span("serve.decode.prepare"):
+                toks = np.zeros((self.slots, 1), np.int32)
+                mask = np.zeros((self.slots,), bool)
+                for s, req in self.active.items():
+                    toks[s, 0] = req.generated[-1]
+                    mask[s] = True
+                pos = jnp.asarray(self.pos)    # (slots,) vectorized cursor
+                tables = (self.pool.device_tables()
+                          if self.pool is not None else None)
+                toks_dev, mask_dev = jnp.asarray(toks), jnp.asarray(mask)
+                f = fault if fault is not None else ModelFault.none()
+                meta = self._take_injection_meta("manual") \
+                    if fault is not None else None
+                # a sticky permanent fault models a faulty UNIT: it
+                # corrupts the retry exactly like the attempt (retry
+                # cannot clear it — the detect->recompute loop's
+                # transient-fault assumption breaks, which is the
+                # 2205.12177 detection gap this campaign mode
+                # exercises); transient/manual faults retry clean as
+                # before
+                retry_f = f if (meta is not None
+                                and meta.get("kind") == "permanent") \
+                    else ModelFault.none()
 
-        prev_cache = self.cache
-        prev_keys = self.keys
-        with self._tr.span("decode_step",
-                           {"tokens": len(self.active)}) as sp:
-            nxt, new_cache, flag, nkeys = self._decode(
-                self.params, jnp.asarray(toks), prev_cache, pos,
-                jnp.asarray(mask), prev_keys, tables, f)
-            sp.fence(nxt, flag)
-        self.stats.steps += 1
-        if self.pool is not None:
-            # per-step occupancy samples: benchmarks report mean/median/
-            # peak blocks_used (the paged capacity win) without poking
-            # mid-run
-            self.stats.observe_blocks_used(self.pool.blocks_used)
-            self.stats.blocks_shared_peak = max(
-                self.stats.blocks_shared_peak, self.pool.blocks_shared)
-        with self._tr.span("abft_check", {"phase": "decode"}):
-            faulted = bool(flag)
-        if faulted:
-            # ABFT detection -> recompute from pre-step state (clean run,
-            # same per-slot keys: the retry resamples the same token)
-            self.stats.faults_detected += 1
-            self._tr.instant("fault_detected", {"phase": "decode"})
-            for _ in range(self.policy.max_retries):
-                self.stats.retries += 1
-                with self._tr.span("abft_retry",
-                                   {"phase": "decode"}) as sp:
-                    nxt, new_cache, flag, nkeys = self._decode(
-                        self.params, jnp.asarray(toks), prev_cache, pos,
-                        jnp.asarray(mask), prev_keys, tables, retry_f)
-                    sp.fence(nxt, flag)
-                if not bool(flag):
-                    break
-            if meta is not None:
-                self._record_injection(
-                    meta, "decode",
-                    "uncorrected" if bool(flag) else "corrected")
-            if bool(flag):
-                self.stats.hard_faults += 1
-                self._tr.instant("hard_fault", {"phase": "decode"})
-                if not self.policy.evict_on_hard_fault:
-                    raise RuntimeError("persistent fault after retry")
-                # the flag is step-global: every in-flight request may be
-                # corrupted, so evict them all with recorded errors and
-                # keep the engine alive for subsequent admissions (shared
-                # blocks survive as long as ANY sharer was admitted later
-                # with live references — refcounts gate the free list)
+            prev_cache = self.cache
+            prev_keys = self.keys
+
+            def attempt(fa):
+                return self._decode(self.params, toks_dev, prev_cache, pos,
+                                    mask_dev, prev_keys, tables, fa)
+
+            with tr.span("serve.decode.dispatch"):
+                nxt, new_cache, flag, nkeys = attempt(f)
+            self.stats.steps += 1
+            if self.pool is not None:
+                # per-step occupancy samples: benchmarks report mean/
+                # median/peak blocks_used (the paged capacity win)
+                # without poking mid-run
+                self.stats.observe_blocks_used(self.pool.blocks_used)
+                self.stats.blocks_shared_peak = max(
+                    self.stats.blocks_shared_peak, self.pool.blocks_shared)
+            with tr.span("serve.decode.wait", {"what": "flag"}):
+                faulted = bool(flag)
+            if faulted:
+                # ABFT detection -> recompute from pre-step state (clean
+                # run, same per-slot keys: the retry resamples the same
+                # token)
+                self.stats.faults_detected += 1
+                tr.instant("fault_detected", {"phase": "decode"})
+                for _ in range(self.policy.max_retries):
+                    self.stats.retries += 1
+                    with tr.span("serve.retry", {"call": "decode"}):
+                        with tr.span("serve.decode.dispatch"):
+                            nxt, new_cache, flag, nkeys = attempt(retry_f)
+                        with tr.span("serve.decode.wait",
+                                     {"what": "flag"}):
+                            faulted = bool(flag)
+                    if not faulted:
+                        break
+                if meta is not None:
+                    self._record_injection(
+                        meta, "decode",
+                        "uncorrected" if faulted else "corrected")
+                if faulted:
+                    self.stats.hard_faults += 1
+                    tr.instant("hard_fault", {"phase": "decode"})
+                    if not self.policy.evict_on_hard_fault:
+                        raise RuntimeError("persistent fault after retry")
+                    # the flag is step-global: every in-flight request may
+                    # be corrupted, so evict them all with recorded errors
+                    # and keep the engine alive for subsequent admissions
+                    # (shared blocks survive as long as ANY sharer was
+                    # admitted later with live references — refcounts
+                    # gate the free list)
+                    for s, req in list(self.active.items()):
+                        self._finish(req, "hard_fault:decode", evict=True)
+                        del self.active[s]
+                        self._release(s)
+                    return {}
+            elif meta is not None:
+                # UNDETECTED injection: shadow-stream comparison — re-run
+                # the same call clean from the pre-step state and
+                # compare.  The faulted result stays committed (realistic
+                # propagation); only the classification consumes the
+                # shadow.
+                outcome, extra = ("undetected", {})
+                if self.classify_injections:
+                    s_nxt, s_cache, _, _ = attempt(ModelFault.none())
+                    outcome, extra = self._shadow_outcome(
+                        nxt, new_cache, (s_nxt, s_cache))
+                self._record_injection(meta, "decode", outcome, **extra)
+
+            with tr.span("serve.decode.wait", {"what": "tokens"}):
+                nxt = np.asarray(nxt)
+            with tr.span("serve.decode.commit"):
+                self.cache = new_cache
+                self.keys = nkeys
+                out = {}
+                finished = []
+                now = time.perf_counter()
                 for s, req in list(self.active.items()):
-                    self._finish(req, "hard_fault:decode", evict=True)
+                    t = int(nxt[s])
+                    req.generated.append(t)
+                    req.times.append(now)
+                    self.pos[s] += 1
+                    out[req.uid] = t
+                    self.stats.tokens += 1
+                    if len(req.generated) >= req.max_new_tokens:
+                        self._finish(req)
+                        finished.append(s)
+                for s in finished:
                     del self.active[s]
                     self._release(s)
-                return {}
-        elif meta is not None:
-            # UNDETECTED injection: shadow-stream comparison — re-run
-            # the same call clean from the pre-step state and compare.
-            # The faulted result stays committed (realistic propagation);
-            # only the classification consumes the shadow.
-            outcome, extra = ("undetected", {})
-            if self.classify_injections:
-                s_nxt, s_cache, _, _ = self._decode(
-                    self.params, jnp.asarray(toks), prev_cache, pos,
-                    jnp.asarray(mask), prev_keys, tables,
-                    ModelFault.none())
-                outcome, extra = self._shadow_outcome(
-                    nxt, new_cache, (s_nxt, s_cache))
-            self._record_injection(meta, "decode", outcome, **extra)
-        self.cache = new_cache
-        self.keys = nkeys
-
-        out = {}
-        nxt = np.asarray(nxt)
-        finished = []
-        now = time.perf_counter()
-        for s, req in list(self.active.items()):
-            t = int(nxt[s])
-            req.generated.append(t)
-            req.times.append(now)
-            self.pos[s] += 1
-            out[req.uid] = t
-            self.stats.tokens += 1
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(req)
-                finished.append(s)
-        for s in finished:
-            del self.active[s]
-            self._release(s)
-        self._last_decode_tokens = len(out)
+                self._last_decode_tokens = len(out)
         return out
 
-    # ------------------------------------------------- speculative decoding
     def _serve_core(self, fault: ModelFault | None = None) -> dict:
         """Route one resident-slot step: the speculative verify core
         when a proposer is attached, else plain decode.  Leaves
@@ -1300,127 +1372,136 @@ class ServeEngine:
         rollback to the last accepted position is simply "don't
         advance" — and a sticky permanent exhausts the retry budget and
         evicts as decode does.  Returns {uid: last emitted token}."""
-        if self.draft_auto:
-            self._retune_draft_len()
-        proposals: dict = {}
-        for s, req in sorted(self.active.items()):
-            budget = min(self.draft_len,
-                         req.max_new_tokens - len(req.generated) - 1)
-            d = (np.asarray(self.spec.propose(req, budget), np.int32)
-                 if budget > 0 else np.zeros((0,), np.int32))
-            proposals[s] = d[:max(0, budget)]
-            self.stats.draft_proposed += len(proposals[s])
-        # paged growth/COW guard over the WHOLE window (tables frozen
-        # across the attempt/retry window, same as decode)
-        self._copy_cow_blocks(self.scheduler.grow_for_verify(
-            {s: len(d) for s, d in proposals.items()}))
-        if not self.active:
-            return {}
-        T = self.draft_len + 1
-        toks = np.zeros((self.slots, T), np.int32)
-        mask = np.zeros((self.slots,), bool)
-        valid = np.zeros((self.slots,), np.int32)
-        for s, req in self.active.items():
-            d = proposals[s]
-            toks[s, 0] = req.generated[-1]
-            toks[s, 1:1 + len(d)] = d
-            mask[s] = True
-            valid[s] = len(d) + 1
-        window_tokens = int(valid.sum())
-        pos = jnp.asarray(self.pos)
-        tables = (self.pool.device_tables()
-                  if self.pool is not None else None)
-        f = fault if fault is not None else ModelFault.none()
-        meta = self._take_injection_meta("manual") \
-            if fault is not None else None
-        retry_f = f if (meta is not None
-                        and meta.get("kind") == "permanent") \
-            else ModelFault.none()
+        tr = self._tr
+        with tr.span("serve.verify", {"rows": len(self.active)}) as call:
+            with tr.span("serve.verify.prepare"):
+                if self.draft_auto:
+                    self._retune_draft_len()
+                proposals: dict = {}
+                for s, req in sorted(self.active.items()):
+                    budget = min(self.draft_len,
+                                 req.max_new_tokens - len(req.generated) - 1)
+                    d = (np.asarray(self.spec.propose(req, budget), np.int32)
+                         if budget > 0 else np.zeros((0,), np.int32))
+                    proposals[s] = d[:max(0, budget)]
+                    self.stats.draft_proposed += len(proposals[s])
+                # paged growth/COW guard over the WHOLE window (tables
+                # frozen across the attempt/retry window, same as decode)
+                self._copy_cow_blocks(self.scheduler.grow_for_verify(
+                    {s: len(d) for s, d in proposals.items()}))
+                if not self.active:
+                    return {}
+                T = self.draft_len + 1
+                toks = np.zeros((self.slots, T), np.int32)
+                mask = np.zeros((self.slots,), bool)
+                valid = np.zeros((self.slots,), np.int32)
+                for s, req in self.active.items():
+                    d = proposals[s]
+                    toks[s, 0] = req.generated[-1]
+                    toks[s, 1:1 + len(d)] = d
+                    mask[s] = True
+                    valid[s] = len(d) + 1
+                window_tokens = int(valid.sum())
+                pos = jnp.asarray(self.pos)
+                tables = (self.pool.device_tables()
+                          if self.pool is not None else None)
+                dev = (jnp.asarray(toks), jnp.asarray(mask),
+                       jnp.asarray(valid))
+                f = fault if fault is not None else ModelFault.none()
+                meta = self._take_injection_meta("manual") \
+                    if fault is not None else None
+                retry_f = f if (meta is not None
+                                and meta.get("kind") == "permanent") \
+                    else ModelFault.none()
+            call.set_args(shape=f"{self.slots}x{T}", tokens=window_tokens,
+                          draft_len=self.draft_len)
 
-        prev_cache = self.cache
-        prev_keys = self.keys
-        dev = (jnp.asarray(toks), jnp.asarray(mask), jnp.asarray(valid))
+            prev_cache = self.cache
+            prev_keys = self.keys
 
-        def attempt(fa):
-            return self._verify(self.params, dev[0], prev_cache, pos,
-                                dev[1], dev[2], prev_keys, tables, fa)
+            def attempt(fa):
+                return self._verify(self.params, dev[0], prev_cache, pos,
+                                    dev[1], dev[2], prev_keys, tables, fa)
 
-        with self._tr.span("verify_step",
-                           {"tokens": window_tokens,
-                            "draft_len": self.draft_len}) as sp:
-            logits, new_cache, flag, nkeys = attempt(f)
-            sp.fence(logits, flag)
-        self.stats.steps += 1
-        if self.pool is not None:
-            self.stats.observe_blocks_used(self.pool.blocks_used)
-            self.stats.blocks_shared_peak = max(
-                self.stats.blocks_shared_peak, self.pool.blocks_shared)
-        with self._tr.span("abft_check", {"phase": "verify"}):
-            faulted = bool(flag)
-        if faulted:
-            self.stats.faults_detected += 1
-            self._tr.instant("fault_detected", {"phase": "verify"})
-            for _ in range(self.policy.max_retries):
-                self.stats.retries += 1
-                self.stats.verify_retries += 1
-                with self._tr.span("abft_retry",
-                                   {"phase": "verify"}) as sp:
-                    logits, new_cache, flag, nkeys = attempt(retry_f)
-                    sp.fence(logits, flag)
-                if not bool(flag):
-                    break
-            if meta is not None:
-                self._record_injection(
-                    meta, "verify",
-                    "uncorrected" if bool(flag) else "corrected")
-            if bool(flag):
-                self.stats.hard_faults += 1
-                self._tr.instant("hard_fault", {"phase": "verify"})
-                if not self.policy.evict_on_hard_fault:
-                    raise RuntimeError("persistent fault after retry")
+            with tr.span("serve.verify.dispatch"):
+                logits, new_cache, flag, nkeys = attempt(f)
+            self.stats.steps += 1
+            if self.pool is not None:
+                self.stats.observe_blocks_used(self.pool.blocks_used)
+                self.stats.blocks_shared_peak = max(
+                    self.stats.blocks_shared_peak, self.pool.blocks_shared)
+            with tr.span("serve.verify.wait", {"what": "flag"}):
+                faulted = bool(flag)
+            if faulted:
+                self.stats.faults_detected += 1
+                tr.instant("fault_detected", {"phase": "verify"})
+                for _ in range(self.policy.max_retries):
+                    self.stats.retries += 1
+                    self.stats.verify_retries += 1
+                    with tr.span("serve.retry", {"call": "verify"}):
+                        with tr.span("serve.verify.dispatch"):
+                            logits, new_cache, flag, nkeys = attempt(
+                                retry_f)
+                        with tr.span("serve.verify.wait",
+                                     {"what": "flag"}):
+                            faulted = bool(flag)
+                    if not faulted:
+                        break
+                if meta is not None:
+                    self._record_injection(
+                        meta, "verify",
+                        "uncorrected" if faulted else "corrected")
+                if faulted:
+                    self.stats.hard_faults += 1
+                    tr.instant("hard_fault", {"phase": "verify"})
+                    if not self.policy.evict_on_hard_fault:
+                        raise RuntimeError("persistent fault after retry")
+                    for s, req in list(self.active.items()):
+                        self._finish(req, "hard_fault:verify", evict=True)
+                        del self.active[s]
+                        self._release(s)
+                    return {}
+            elif meta is not None:
+                outcome, extra = ("undetected", {})
+                if self.classify_injections:
+                    s_logits, s_cache, _, _ = attempt(ModelFault.none())
+                    outcome, extra = self._shadow_outcome(
+                        logits, new_cache, (s_logits, s_cache))
+                self._record_injection(meta, "verify", outcome, **extra)
+
+            with tr.span("serve.verify.wait", {"what": "tokens"}):
+                logits = np.asarray(logits)
+            with tr.span("serve.verify.commit"):
+                self.cache = new_cache
+                self.keys = nkeys
+                out = {}
+                finished = []
+                now = time.perf_counter()
                 for s, req in list(self.active.items()):
-                    self._finish(req, "hard_fault:verify", evict=True)
+                    d = proposals[s]
+                    rows = logits[s, :len(d) + 1]
+                    if self.temperature <= 0.0:
+                        targets = np.argmax(rows, axis=-1).astype(np.int32)
+                        emitted = greedy_accept(d, targets)
+                    else:
+                        emitted = rejection_sample(
+                            d, target_probs(rows, self.temperature,
+                                            self.top_k),
+                            prev_keys[s])
+                    self.stats.draft_accepted += len(emitted) - 1
+                    for t in emitted:
+                        req.generated.append(int(t))
+                        req.times.append(now)
+                        self.stats.tokens += 1
+                    self.pos[s] += len(emitted)
+                    out[req.uid] = int(emitted[-1])
+                    if len(req.generated) >= req.max_new_tokens:
+                        self._finish(req)
+                        finished.append(s)
+                for s in finished:
                     del self.active[s]
                     self._release(s)
-                return {}
-        elif meta is not None:
-            outcome, extra = ("undetected", {})
-            if self.classify_injections:
-                s_logits, s_cache, _, _ = attempt(ModelFault.none())
-                outcome, extra = self._shadow_outcome(
-                    logits, new_cache, (s_logits, s_cache))
-            self._record_injection(meta, "verify", outcome, **extra)
-        self.cache = new_cache
-        self.keys = nkeys
-
-        out = {}
-        logits = np.asarray(logits)
-        finished = []
-        now = time.perf_counter()
-        for s, req in list(self.active.items()):
-            d = proposals[s]
-            rows = logits[s, :len(d) + 1]
-            if self.temperature <= 0.0:
-                targets = np.argmax(rows, axis=-1).astype(np.int32)
-                emitted = greedy_accept(d, targets)
-            else:
-                emitted = rejection_sample(
-                    d, target_probs(rows, self.temperature, self.top_k),
-                    prev_keys[s])
-            self.stats.draft_accepted += len(emitted) - 1
-            for t in emitted:
-                req.generated.append(int(t))
-                req.times.append(now)
-                self.stats.tokens += 1
-            self.pos[s] += len(emitted)
-            out[req.uid] = int(emitted[-1])
-            if len(req.generated) >= req.max_new_tokens:
-                self._finish(req)
-                finished.append(s)
-        for s in finished:
-            del self.active[s]
-            self._release(s)
-        self._last_decode_tokens = window_tokens
+                self._last_decode_tokens = window_tokens
         return out
 
     def run(self, requests: list, fault_at: tuple | None = None,

@@ -498,24 +498,3 @@ class Scheduler:
                 self.finish(req, "oom:kv_blocks", evict=True)
                 self.release(s)
         return cow_pairs
-        for s in sorted(self.active):
-            # copy-on-write guard: if this step's write lands in a
-            # block another slot still references, redirect to a
-            # fresh copy first.  Admission COWs the shared partial
-            # tail eagerly, so this only fires on exotic lifecycles —
-            # but scribbling on a sharer's block is silent corruption,
-            # so the guard is unconditional.
-            idx = int(self.pos[s]) // self.pool.block_size
-            if idx < self.pool.slot_blocks(s) and \
-                    self.pool.refcount[self.pool.tables[s, idx]] > 1:
-                if self.pool.blocks_free == 0:
-                    req = self.active.pop(s)
-                    self.finish(req, "oom:kv_blocks", evict=True)
-                    self.release(s)
-                    continue
-                cow_pairs.append(self.pool.try_cow(s, idx))
-            if not self.pool.try_grow(s, int(self.pos[s]) + 1):
-                req = self.active.pop(s)
-                self.finish(req, "oom:kv_blocks", evict=True)
-                self.release(s)
-        return cow_pairs

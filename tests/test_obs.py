@@ -7,6 +7,7 @@ driver's --metrics-out/--trace-out artifacts.
 
 import json
 import math
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +54,41 @@ def _reqs(spec):
     return [Request(uid=i, prompt=np.arange(1, 1 + L, dtype=np.int32),
                     max_new_tokens=n)
             for i, (L, n) in enumerate(spec)]
+
+
+def _profiled_spans(log_dir) -> list:
+    """[(name, start_ns, end_ns, args)] of the ``serve.*`` host events in
+    the one ``.xplane.pb`` under ``log_dir``, by start time."""
+    paths = list(Path(log_dir).rglob("*.xplane.pb"))
+    assert len(paths) == 1, paths
+    data = jax.profiler.ProfileData.from_file(str(paths[0]))
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _children(parent, spans) -> list:
+    """Names of the spans whose innermost enclosing span is ``parent``,
+    in start order."""
+    def inside(a, b):
+        return b is not a and b[1] <= a[1] and a[2] <= b[2]
+
+    out = []
+    for sp in spans:
+        if not inside(sp, parent):
+            continue
+        if not any(inside(sp, mid) and inside(mid, parent)
+                   for mid in spans):
+            out.append(sp[0])
+    return out
 
 
 # ==================================================== metrics registry
@@ -147,28 +183,6 @@ class TestMetrics:
         assert buckets[-1][0] == "+Inf"
         assert buckets[-1][1] == snap["lat"]["series"][0]["count"] == 1
 
-    def test_prometheus_exposition(self):
-        r = MetricsRegistry()
-        c = r.counter("req_total", "requests served",
-                      labels=("scheme",))
-        c.labels(scheme='glo"bal\\x\n').inc(2)
-        h = r.histogram("lat_seconds", "latency", buckets=(0.5, 1.0))
-        h.observe(0.3)
-        h.observe(5.0)
-        text = r.render_prometheus()
-        lines = text.splitlines()
-        assert "# HELP req_total requests served" in lines
-        assert "# TYPE req_total counter" in lines
-        # label escaping: backslash, quote, newline
-        assert 'req_total{scheme="glo\\"bal\\\\x\\n"} 2' in lines
-        assert "# TYPE lat_seconds histogram" in lines
-        assert 'lat_seconds_bucket{le="0.5"} 1' in lines
-        assert 'lat_seconds_bucket{le="1"} 1' in lines
-        assert 'lat_seconds_bucket{le="+Inf"} 2' in lines
-        assert "lat_seconds_sum 5.3" in lines
-        assert "lat_seconds_count 2" in lines
-        assert text.endswith("\n")
-
     def test_remove_series(self):
         g = MetricsRegistry().gauge("g", labels=("w",))
         g.labels(w="a").set(1)
@@ -200,16 +214,37 @@ class TestTrace:
         assert doc["traceEvents"] == evs
         assert doc["otherData"]["dropped_events"] == 0
 
-    def test_disabled_tracer_is_noop(self):
-        tr = Tracer(enabled=False)
-        s1 = tr.span("a")
-        s2 = tr.span("b")
-        assert s1 is s2                  # shared null span, no alloc
-        with s1 as sp:
-            sp.fence(object())           # must not touch jax
-            sp.set_args(x=1)
+    def test_disabled_tracer_records_nothing(self):
+        """A disabled tracer still opens profiler annotations but keeps
+        no in-memory record and calls no sink."""
+        seen = []
+        tr = Tracer(enabled=False, sink=seen.append)
+        with tr.span("a", {"k": 1}) as sp:
+            with tr.span("b"):
+                sp.set_args(x=1)
         tr.instant("i")
-        assert tr.events == [] and tr.dropped == 0
+        assert tr.events == [] and tr.dropped == 0 and seen == []
+        assert sp.args == {"k": 1, "x": 1}
+
+    def test_span_is_a_profiler_annotation(self, tmp_path):
+        """Spans land in a jax.profiler trace with their args (set late
+        ones too) as metadata, nested as opened, on the same clock as
+        any other TraceMe event — enabled or not."""
+        for enabled in (False, True):
+            tr = Tracer(enabled=enabled)
+            d = tmp_path / str(enabled)
+            with jax.profiler.trace(str(d)):
+                with tr.span("serve.outer", {"rows": 2}) as sp:
+                    with tr.span("serve.inner", {"what": "flag"}):
+                        pass
+                    sp.set_args(prefill=7)
+            evs = _profiled_spans(d)
+            assert [e[0] for e in evs] == ["serve.outer", "serve.inner"]
+            (_, o0, o1, oargs), (_, i0, i1, iargs) = evs
+            assert o0 <= i0 <= i1 <= o1
+            assert oargs == {"rows": 2, "prefill": 7}
+            assert iargs == {"what": "flag"}
+            assert len(tr.events) == (2 if enabled else 0)
 
     def test_max_events_and_dropped(self):
         tr = Tracer(max_events=2)
@@ -376,8 +411,8 @@ def test_blocks_used_decimation_keeps_alignment():
 class TestEngineTelemetry:
     def test_counters_match_and_streams_identical(self, small_model):
         """Mirrored counters equal EngineStats exactly after a run, and
-        the greedy token streams are byte-identical with telemetry
-        (tracing + fencing) enabled or disabled."""
+        the greedy token streams are byte-identical with no telemetry
+        and with the in-memory tracer on and off."""
         _, model, params = small_model
         spec = [(5, 6), (9, 4), (3, 5), (7, 3)]
 
@@ -401,7 +436,14 @@ class TestEngineTelemetry:
                 getattr(eng1.stats, attr)
         assert check_events(tel.tracer.events) == []
         names = {e["name"] for e in tel.tracer.events}
-        assert {"admit", "prefill", "decode_step", "abft_check"} <= names
+        assert {"serve.admit", "serve.prefill", "serve.prefill.wait",
+                "serve.step", "serve.decode", "serve.decode.dispatch",
+                "serve.decode.wait", "serve.decode.commit"} <= names
+        # the same spans with the in-memory record off
+        eng2, reqs2 = run(EngineTelemetry(trace=False))
+        assert [r.generated for r in reqs2] == \
+            [r.generated for r in reqs0]
+        assert eng2.telemetry.tracer.events == []
 
     def test_fault_injection_telemetry(self, small_model):
         """An injected transient fault shows up on every surface: the
@@ -432,7 +474,7 @@ class TestEngineTelemetry:
         assert tel.faults.window_detection_rate > 0.0
         assert tel.faults.ewma_detections > 0.0
         names = [e["name"] for e in tel.tracer.events]
-        assert "abft_retry" in names
+        assert "serve.retry" in names
         assert "fault_detected" in names
         assert check_events(tel.tracer.events) == []
         # the windowed-rate gauges were published at sync time
@@ -478,7 +520,7 @@ class TestEngineTelemetry:
             {Scheme.GLOBAL.value, Scheme.BLOCK_1S.value}
         assert tel.counters_match(eng.stats)
         names = {e["name"] for e in tel.tracer.events}
-        assert "prefill_chunk" in names
+        assert "serve.chunk" in names
         assert check_events(tel.tracer.events) == []
 
     def test_step_latency_histogram_fills(self, small_model):
@@ -490,6 +532,87 @@ class TestEngineTelemetry:
         assert tel.step_latency.count == eng.stats.steps
         cum = tel.step_latency._default().cumulative()
         assert cum[-1][1] == tel.step_latency.count
+
+
+# ============================================ engine spans in a profile
+
+@pytest.fixture(scope="module")
+def profiled_mixed_step(small_model, tmp_path_factory):
+    """A chunked engine with the default (disabled) tracer — the
+    benchmark's engine — profiled over one admission and one mixed step:
+    one resident decode stream beside a prompt's first chunk."""
+    _, model, params = small_model
+    eng = ServeEngine(model, params, slots=2, max_len=64, abft=ABFT,
+                      dtype=jnp.float32, chunk_tokens=16)
+    eng.admit(_reqs([(4, 12)]))
+    while eng._prefill_cursors:
+        eng.step()
+    prompt = Request(uid=7, prompt=np.arange(1, 41, dtype=np.int32),
+                     max_new_tokens=2)
+    log_dir = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(log_dir)):
+        eng.admit([prompt])
+        eng.step()
+    return eng, _profiled_spans(log_dir)
+
+
+class TestEngineProfile:
+    CALL = ["serve.{c}.prepare", "serve.{c}.dispatch", "serve.{c}.wait",
+            "serve.{c}.wait", "serve.{c}.commit"]
+
+    def test_mixed_step_span_tree(self, profiled_mixed_step):
+        eng, spans = profiled_mixed_step
+        assert eng.telemetry is None and not eng._tr.enabled
+        steps = [sp for sp in spans if sp[0] == "serve.step"]
+        assert len(steps) == 1
+        assert _children(steps[0], spans) == [
+            "serve.schedule", "serve.decode", "serve.chunk",
+            "serve.account"]
+        for c in ("decode", "chunk"):
+            call = next(sp for sp in spans if sp[0] == f"serve.{c}")
+            assert _children(call, spans) == [
+                n.format(c=c) for n in self.CALL]
+
+    def test_admit_span_tree(self, profiled_mixed_step):
+        _, spans = profiled_mixed_step
+        admits = [sp for sp in spans if sp[0] == "serve.admit"]
+        assert len(admits) == 1
+        # chunked admission parks the prompt: no model call
+        assert _children(admits[0], spans) == ["serve.schedule",
+                                               "serve.account"]
+        assert admits[0][3] == {"consumed": 1, "admitted": 1}
+        assert admits[0][2] <= min(sp[1] for sp in spans
+                                   if sp[0] == "serve.step")
+
+    def test_span_args(self, profiled_mixed_step):
+        _, spans = profiled_mixed_step
+        by = {}
+        for sp in spans:
+            by.setdefault(sp[0], []).append(sp[3])
+        # 1 resident decode token; 16 - 1 = 15 prompt tokens in the chunk
+        assert by["serve.step"] == [{"decode": 1, "prefill": 15}]
+        assert by["serve.decode"] == [{"rows": 1, "shape": "2x1"}]
+        assert by["serve.chunk"] == [{"rows": 1, "shape": "1x16",
+                                      "uid": 7}]
+        for c in ("decode", "chunk"):
+            assert [a["what"] for a in by[f"serve.{c}.wait"]] == \
+                ["flag", "tokens"]
+
+    def test_spans_add_no_device_sync(self, small_model, monkeypatch):
+        """Recording spans never blocks on the device: the engine's only
+        syncs are the readbacks it makes anyway."""
+        _, model, params = small_model
+        calls = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: calls.append(1) or real(x))
+        eng = ServeEngine(model, params, slots=2, max_len=64, abft=ABFT,
+                          dtype=jnp.float32, chunk_tokens=16,
+                          telemetry=EngineTelemetry(trace=True))
+        eng.run(_reqs([(20, 3), (5, 4)]))
+        assert calls == []
+        names = {e["name"] for e in eng.telemetry.tracer.events}
+        assert {"serve.chunk.wait", "serve.decode.wait"} <= names
 
 
 # ======================================================= heartbeat gauges
@@ -518,8 +641,9 @@ class TestHeartbeatGauges:
         assert all(lab["worker"] != "w1" for lab, _ in alive.series())
         mon.add("w2")
         assert alive.labels(worker="w2").value == 1
-        # prometheus rendering covers the labeled gauges
-        assert 'worker_alive{worker="w0"} 1' in reg.render_prometheus()
+        # the JSON export covers the labeled gauges
+        series = reg.snapshot()["worker_alive"]["series"]
+        assert {"labels": {"worker": "w0"}, "value": 1} in series
 
     def test_no_registry_is_fine(self):
         mon = HeartbeatMonitor(["a"], timeout_s=1.0, clock=lambda: 0.0)
@@ -557,5 +681,5 @@ def test_launch_serve_writes_valid_artifacts(tmp_path):
     assert metrics["engine_stats"]["abft_faults_detected_total"] >= 1
     assert metrics["faultrate"]["total_detections"] >= 1
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"admit", "decode_step", "abft_retry",
+    assert {"serve.admit", "serve.step", "serve.decode", "serve.retry",
             "fault_detected"} <= names
