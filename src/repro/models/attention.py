@@ -1,8 +1,11 @@
 """Attention sublayers: GQA (llama/qwen/stablelm/jamba/vlm), absorbed MLA
 (deepseek-v3), and cross-attention (whisper decoder / vlm image layers).
 
-All projections run through the ABFT-protected dense().  Decode paths
-write/read a KV cache passed explicitly (functional style).
+All projections run through the ABFT-protected dense().  Cache paths
+take a layer's STACKED cache leaves (R, ...) and its index ``layer`` into
+them (models/model.py carries the stack through the layer scan): they
+write only the rows they produce and read their own entry where it is
+consumed (functional style).
 """
 
 from __future__ import annotations
@@ -15,6 +18,8 @@ from repro.configs.base import ModelConfig
 from repro.models.layers import (
     LayerCtx,
     apply_rope,
+    cache_layer,
+    cache_write,
     chunked_attention,
     decode_attention,
     dense,
@@ -149,38 +154,62 @@ def gqa_forward(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
     return out, or_flags(flag, f_attn, f)
 
 
-def _row_scatter(cache_leaf, new, pos):
-    """Per-row KV scatter: write ``new[b]`` into ``cache_leaf[b]`` at its
-    own row position ``pos[b]`` (vectorized decode cursor)."""
-    def one(c, n, p):
-        start = (p,) + (0,) * (c.ndim - 1)
-        return jax.lax.dynamic_update_slice(c, n, start)
+def _write_rows(leaf, layer, rows, starts, new, counts):
+    """Write ``new[a, :counts[a]]`` (A, T, ...) into entry ``layer`` of the
+    stacked cache leaf (R, B, S, ...), row ``rows[a]``, at positions
+    ``starts[a]``, ``starts[a] + 1``, ...; positions at or past S drop.
+    Rows past ``counts[a]`` (padding) are never written, so a bucketed
+    pad near S cannot clamp backwards onto rows already written, as a
+    ``dynamic_update_slice`` of the whole of ``new[a]`` would (the
+    chunked-prefill resume path: earlier chunks occupy ``[0, starts[a])``).
 
-    return jax.vmap(one)(cache_leaf, new.astype(cache_leaf.dtype), pos)
+    Each row reads a window of at least 8 positions of its entry, selects
+    its new values in and writes the window back in place.  The window
+    takes the layout of the loop-carried leaf, so the device keeps the
+    cache in the layout it stores it in (on TPU v5e a 64-wide head puts
+    positions minor, unpadded).  One scatter of the new rows instead
+    wants the cache positions-major: even with the carry pinned
+    (keep_storage_layout) the compiler then relayouts the whole carried
+    cache into the scatter's layout and back in every layer, four
+    cache-sized copies a layer."""
+    S = leaf.shape[2]
+    T = new.shape[1]
+    W = min(S, max(T, 8))
+    j = jnp.arange(W, dtype=jnp.int32)
+    zeros = (0,) * (leaf.ndim - 3)
+    new = new.astype(leaf.dtype)
+    for a in range(new.shape[0]):
+        start = starts[a].astype(jnp.int32)
+        lo = jnp.clip(start, 0, S - W)
+        src = j + (lo - start)                     # window slot -> new row
+        put = (src >= 0) & (src < counts[a])
+        at = (layer, rows[a], lo) + zeros
+        win = jax.lax.dynamic_slice(leaf, at, (1, 1, W) + leaf.shape[3:])
+        vals = jnp.take(new[a], jnp.clip(src, 0, T - 1), axis=0)
+        put = put.reshape((1, 1, W) + (1,) * len(zeros))
+        leaf = jax.lax.dynamic_update_slice(
+            leaf, jnp.where(put, vals[None, None], win), at)
+    return leaf
 
 
-def _slot_prefill_write(cache_leaf, new, slots, L):
-    """Write ``new`` (A, L, ...) into rows ``slots`` of the engine cache
-    (B_engine, S_max, ...) at positions [0, L)."""
-    return cache_leaf.at[slots, :L].set(new.astype(cache_leaf.dtype))
+def _row_scatter(leaf, layer, new, pos):
+    """Per-row KV write: ``new[b, 0]`` lands in entry ``layer`` of the
+    stacked leaf (R, B, S, ...) at row ``b``, position ``pos[b]``
+    (vectorized decode cursor; clamped into the row like a
+    ``dynamic_update_slice``)."""
+    B, S = new.shape[0], leaf.shape[2]
+    return _write_rows(leaf, layer, jnp.arange(B, dtype=jnp.int32),
+                       jnp.clip(pos, 0, S - 1), new,
+                       jnp.ones((B,), jnp.int32))
 
 
-def _slot_prefill_write_at(cache_leaf, new, slots, starts, lengths):
-    """Write ``new`` (A, L, ...) into rows ``slots`` of the engine cache at
-    per-row start offsets: ``new[a, t]`` lands at position ``starts[a] + t``
-    for ``t < lengths[a]`` (the chunked-prefill resume path — earlier chunks
-    already occupy ``[0, starts[a])``).  Padding positions are routed past
-    the cache depth and dropped, so a bucketed pad near ``max_len`` can
-    never clamp backwards onto previously written chunks the way a
-    ``dynamic_update_slice`` would."""
-    S = cache_leaf.shape[1]
+def _slot_prefill_write(leaf, layer, new, slots):
+    """Write ``new`` (A, L, ...) into rows ``slots`` of entry ``layer`` of
+    the stacked engine cache (R, B_engine, S_max, ...) at positions
+    [0, L)."""
     A, L = new.shape[0], new.shape[1]
-    t = jnp.arange(L, dtype=jnp.int32)
-    pos = starts[:, None].astype(jnp.int32) + t[None, :]
-    pos = jnp.where(t[None, :] < lengths[:, None], pos, S)   # drop padding
-    rows = jnp.broadcast_to(slots[:, None], (A, L))
-    return cache_leaf.at[rows, pos].set(
-        new.astype(cache_leaf.dtype), mode="drop")
+    return _write_rows(leaf, layer, slots, jnp.zeros((A,), jnp.int32), new,
+                       jnp.full((A,), L, jnp.int32))
 
 
 def _vec_positions(pos, B):
@@ -189,9 +218,10 @@ def _vec_positions(pos, B):
 
 
 def gqa_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions, cache,
-                slots=None, lengths=None, starts=None):
+                layer, slots=None, lengths=None, starts=None):
     """Prefill: run full attention AND fill the cache.  cache: dict with
-    'k','v' of shape (B, S_max, KV, hd).
+    stacked 'k','v' of shape (R, B, S_max, KV, hd); this layer owns entry
+    ``layer`` and writes only the rows it produces there.
 
     ``slots``/``lengths`` (continuous-batching path): x is the admission
     batch (A, L, D) padded to a common L; k/v rows are scattered into the
@@ -212,89 +242,80 @@ def gqa_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions, cache,
     if starts is None:
         out = chunked_attention(q, k, v, causal=True, lengths=lengths)
         if slots is None:
-            new_cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
-            }
+            new_cache = {"k": cache_write(cache["k"], layer, k),
+                         "v": cache_write(cache["v"], layer, v)}
         else:
             new_cache = {
-                "k": _slot_prefill_write(cache["k"], k, slots, L),
-                "v": _slot_prefill_write(cache["v"], v, slots, L),
+                "k": _slot_prefill_write(cache["k"], layer, k, slots),
+                "v": _slot_prefill_write(cache["v"], layer, v, slots),
             }
     else:
         assert slots is not None, "chunked prefill needs slot targets"
         new_cache = {
-            "k": _slot_prefill_write_at(cache["k"], k, slots, starts,
-                                        lengths),
-            "v": _slot_prefill_write_at(cache["v"], v, slots, starts,
-                                        lengths),
+            "k": _write_rows(cache["k"], layer, slots, starts, k, lengths),
+            "v": _write_rows(cache["v"], layer, slots, starts, v, lengths),
         }
         out = chunked_attention(
-            q, jnp.take(new_cache["k"], slots, axis=0),
-            jnp.take(new_cache["v"], slots, axis=0),
+            q, jnp.take(cache_layer(new_cache["k"], layer), slots, axis=0),
+            jnp.take(cache_layer(new_cache["v"], layer), slots, axis=0),
             causal=True, q_offset=starts, lengths=starts + lengths)
     out = out.reshape(B, L, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
     return out, new_cache, or_flags(flag, f)
 
 
-def gqa_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache):
+def gqa_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, layer):
     """One-token decode.  x: (B, 1, D); pos: scalar or (B,) per-slot
-    position vector; cache k/v: (B, S_max, KV, hd).  Each row writes its
-    new k/v at its own cursor and attends its own valid prefix."""
+    position vector; cache k/v: stacked (R, B, S_max, KV, hd), this
+    layer's entry ``layer``.  Each row writes its new k/v at its own
+    cursor and attends its own valid prefix."""
     B = x.shape[0]
     pos = _vec_positions(pos, B)
     positions = pos[:, None]
     q, k, v, flag = _qkv(x, p, cfg, ctx, positions)
-    ck = _row_scatter(cache["k"], k, pos)
-    cv = _row_scatter(cache["v"], v, pos)
+    ck = _row_scatter(cache["k"], layer, k, pos)
+    cv = _row_scatter(cache["v"], layer, v, pos)
+    lk, lv = cache_layer(ck, layer), cache_layer(cv, layer)
     if ctx.abft.flash_attention:
         from repro.kernels.flash_ops import flash_decode
 
-        out, chk = flash_decode(q, ck, cv, pos + 1)
+        out, chk = flash_decode(q, lk, lv, pos + 1)
         f_attn = chk.flag
     else:
-        out = decode_attention(q, ck, cv, pos + 1)
+        out = decode_attention(q, lk, lv, pos + 1)
         f_attn = jnp.zeros((), bool)
     out = out.reshape(B, 1, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
     return out, {"k": ck, "v": cv}, or_flags(flag, f_attn, f)
 
 
-def _window_scatter(cache_leaf, new, pos, valid):
-    """Write ``new`` (B, T, ...) into ``cache_leaf`` (B, S, ...) at rows
-    ``pos[b] .. pos[b] + T - 1``, keeping only the first ``valid[b]``
-    rows.  Out-of-window rows route past the cache depth and DROP — a
-    ``dynamic_update_slice`` would clamp a near-budget window backwards
-    onto committed keys (the padded verify T is uniform across slots;
-    per-slot draft budgets are not)."""
-    S = cache_leaf.shape[1]
-    B, T = new.shape[0], new.shape[1]
-    t = jnp.arange(T, dtype=jnp.int32)
-    posns = pos[:, None].astype(jnp.int32) + t[None, :]
-    posns = jnp.where(t[None, :] < valid[:, None], posns, S)
-    rows = jnp.broadcast_to(
-        jnp.arange(B, dtype=jnp.int32)[:, None], (B, T))
-    return cache_leaf.at[rows, posns].set(
-        new.astype(cache_leaf.dtype), mode="drop")
+def _window_scatter(leaf, layer, new, pos, valid):
+    """Write ``new`` (B, T, ...) into entry ``layer`` of the stacked leaf
+    (R, B, S, ...) at rows ``pos[b] .. pos[b] + T - 1``, keeping only the
+    first ``valid[b]`` rows.  Out-of-window rows are never written — a
+    ``dynamic_update_slice`` of the whole window would clamp a
+    near-budget window backwards onto committed keys (the padded verify
+    T is uniform across slots; per-slot draft budgets are not)."""
+    return _write_rows(leaf, layer, jnp.arange(new.shape[0], dtype=jnp.int32),
+                       pos, new, valid)
 
 
-def gqa_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, valid):
+def gqa_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, layer,
+               valid):
     """Speculative verify: x (B, T, D) holds each row's last committed
     token followed by its draft window; row b writes its first
-    ``valid[b]`` k/v rows at positions ``pos[b]..`` and every query
-    attends its own causal prefix (verify_attention).  Rows beyond
-    ``valid`` are shape ballast (uniform T across slots) — their writes
-    drop and their logits are discarded host-side."""
+    ``valid[b]`` k/v rows at positions ``pos[b]..`` of entry ``layer``
+    and every query attends its own causal prefix (verify_attention).
+    Rows beyond ``valid`` are shape ballast (uniform T across slots) —
+    their writes drop and their logits are discarded host-side."""
     B, T, _ = x.shape
     pos = _vec_positions(pos, B)
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     q, k, v, flag = _qkv(x, p, cfg, ctx, positions)
-    ck = _window_scatter(cache["k"], k, pos, valid)
-    cv = _window_scatter(cache["v"], v, pos, valid)
-    out = verify_attention(q, ck, cv, pos + 1)
+    ck = _window_scatter(cache["k"], layer, k, pos, valid)
+    cv = _window_scatter(cache["v"], layer, v, pos, valid)
+    out = verify_attention(q, cache_layer(ck, layer), cache_layer(cv, layer),
+                           pos + 1)
     out = out.reshape(B, T, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
     return out, {"k": ck, "v": cv}, or_flags(flag, f)
@@ -303,11 +324,12 @@ def gqa_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, valid):
 # ---------------------------------------------------------------- paged GQA
 
 def gqa_paged_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
-                      cache, tables, lengths, starts=None):
+                      cache, layer, tables, lengths, starts=None):
     """Paged prefill: same ragged attention as the dense continuous-
     batching path (prompts attend only themselves), but k/v scatter into
-    the block pool at ``tables[a, t // block_size]`` instead of dense
-    engine rows.  cache k/v: (NB, BS, KV, hd); tables: (A, W).
+    entry ``layer`` of the stacked block pool at
+    ``tables[a, t // block_size]`` instead of dense engine rows.  cache
+    k/v: (R, NB, BS, KV, hd); tables: (A, W).
 
     ``starts`` (A,) int32 selects the prefix-sharing SUFFIX path: x holds
     only each row's unshared suffix, whose logical positions begin at
@@ -325,19 +347,19 @@ def gqa_paged_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
     if starts is None:
         out = chunked_attention(q, k, v, causal=True, lengths=lengths)
         new_cache = {
-            "k": paged_scatter_prefill(cache["k"], k, tables, lengths),
-            "v": paged_scatter_prefill(cache["v"], v, tables, lengths),
+            "k": paged_scatter_prefill(cache["k"], layer, k, tables, lengths),
+            "v": paged_scatter_prefill(cache["v"], layer, v, tables, lengths),
         }
     else:
         new_cache = {
-            "k": paged_scatter_prefill(cache["k"], k, tables, lengths,
+            "k": paged_scatter_prefill(cache["k"], layer, k, tables, lengths,
                                        starts=starts),
-            "v": paged_scatter_prefill(cache["v"], v, tables, lengths,
+            "v": paged_scatter_prefill(cache["v"], layer, v, tables, lengths,
                                        starts=starts),
         }
         out = chunked_attention(
-            q, paged_gather(new_cache["k"], tables),
-            paged_gather(new_cache["v"], tables),
+            q, paged_gather(new_cache["k"], layer, tables),
+            paged_gather(new_cache["v"], layer, tables),
             causal=True, q_offset=starts, lengths=starts + lengths)
     out = out.reshape(B, L, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
@@ -345,27 +367,31 @@ def gqa_paged_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
 
 
 def gqa_paged_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
-                     tables):
+                     layer, tables):
     """Paged one-token decode: scatter the new k/v entry at
-    ``tables[b, pos[b] // block_size]``, then attend the slot's own
-    prefix — via the block-table-indexed Pallas flash kernel when the
-    policy enables it, else gather + length-masked reference attention."""
+    ``tables[b, pos[b] // block_size]`` of pool entry ``layer``, then
+    attend the slot's own prefix — via the block-table-indexed Pallas
+    flash kernel when the policy enables it, else gather + length-masked
+    reference attention."""
     from repro.serve.paged_cache import paged_gather, paged_scatter_decode
 
     B = x.shape[0]
     pos = _vec_positions(pos, B)
     positions = pos[:, None]
     q, k, v, flag = _qkv(x, p, cfg, ctx, positions)
-    ck = paged_scatter_decode(cache["k"], k[:, 0], tables, pos)
-    cv = paged_scatter_decode(cache["v"], v[:, 0], tables, pos)
+    ck = paged_scatter_decode(cache["k"], layer, k[:, 0], tables, pos)
+    cv = paged_scatter_decode(cache["v"], layer, v[:, 0], tables, pos)
     if ctx.abft.flash_attention:
         from repro.kernels.flash_ops import flash_decode_paged
 
-        out, chk = flash_decode_paged(q, ck, cv, tables, pos + 1)
+        out, chk = flash_decode_paged(
+            q, cache_layer(ck, layer), cache_layer(cv, layer), tables,
+            pos + 1)
         f_attn = chk.flag
     else:
         out = decode_attention(
-            q, paged_gather(ck, tables), paged_gather(cv, tables), pos + 1)
+            q, paged_gather(ck, layer, tables),
+            paged_gather(cv, layer, tables), pos + 1)
         f_attn = jnp.zeros((), bool)
     out = out.reshape(B, 1, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
@@ -373,7 +399,7 @@ def gqa_paged_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
 
 
 def gqa_paged_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
-                     valid, tables):
+                     layer, valid, tables):
     """Paged speculative verify: the draft window's k/v scatter behind
     the committed prefix via the block tables (the prefix-sharing suffix
     scatter generalizes — per-row starts at the cursor, padding routed
@@ -385,10 +411,11 @@ def gqa_paged_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
     pos = _vec_positions(pos, B)
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     q, k, v, flag = _qkv(x, p, cfg, ctx, positions)
-    ck = paged_scatter_prefill(cache["k"], k, tables, valid, starts=pos)
-    cv = paged_scatter_prefill(cache["v"], v, tables, valid, starts=pos)
+    ck = paged_scatter_prefill(cache["k"], layer, k, tables, valid, starts=pos)
+    cv = paged_scatter_prefill(cache["v"], layer, v, tables, valid, starts=pos)
     out = verify_attention(
-        q, paged_gather(ck, tables), paged_gather(cv, tables), pos + 1)
+        q, paged_gather(ck, layer, tables),
+        paged_gather(cv, layer, tables), pos + 1)
     out = out.reshape(B, T, -1)
     out, f = dense(out, p["wo"], ctx, "attn_out", tag="attn.o")
     return out, {"k": ck, "v": cv}, or_flags(flag, f)
@@ -543,10 +570,11 @@ def mla_forward(x, p, cfg: ModelConfig, ctx: LayerCtx, positions):
 
 
 def mla_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions, cache,
-                slots=None, lengths=None, starts=None):
-    """``starts``: resumable-chunk path (see gqa_prefill) — the chunk's
-    latents land behind the resident prefix rows and attention runs over
-    the slot's cache with per-row causal offsets."""
+                layer, slots=None, lengths=None, starts=None):
+    """Latent rows land in entry ``layer`` of the stacked (R, B, S,
+    c+dr) cache.  ``starts``: resumable-chunk path (see gqa_prefill) —
+    the chunk's latents land behind the resident prefix rows and
+    attention runs over the slot's cache with per-row causal offsets."""
     B, L, _ = x.shape
     q_full, scale, f1 = _mla_q(x, p, cfg, ctx, positions)
     c_kv, k_pe, f2 = _mla_latent_kv(x, p, cfg, ctx, positions)
@@ -555,36 +583,37 @@ def mla_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions, cache,
         out, f3 = _mla_attend(
             q_full, scale, latent, p, cfg, ctx, B, L, lengths=lengths)
         if slots is None:
-            new_latent = jax.lax.dynamic_update_slice(
-                cache["latent"], latent.astype(cache["latent"].dtype),
-                (0, 0, 0))
+            new_latent = cache_write(cache["latent"], layer, latent)
         else:
             new_latent = _slot_prefill_write(
-                cache["latent"], latent, slots, L)
+                cache["latent"], layer, latent, slots)
     else:
         assert slots is not None, "chunked prefill needs slot targets"
-        new_latent = _slot_prefill_write_at(
-            cache["latent"], latent, slots, starts, lengths)
+        new_latent = _write_rows(
+            cache["latent"], layer, slots, starts, latent, lengths)
         out, f3 = _mla_attend(
-            q_full, scale, jnp.take(new_latent, slots, axis=0), p, cfg,
+            q_full, scale,
+            jnp.take(cache_layer(new_latent, layer), slots, axis=0), p, cfg,
             ctx, B, L, lengths=starts + lengths, q_offset=starts)
     return out, {"latent": new_latent}, or_flags(f1, f2, f3)
 
 
-def mla_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache):
+def mla_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, layer):
     B = x.shape[0]
     pos = _vec_positions(pos, B)
     positions = pos[:, None]
     q_full, scale, f1 = _mla_q(x, p, cfg, ctx, positions)
     c_kv, k_pe, f2 = _mla_latent_kv(x, p, cfg, ctx, positions)
     latent_new = jnp.concatenate([c_kv, k_pe], axis=-1)  # (B, 1, c+dr)
-    lat = _row_scatter(cache["latent"], latent_new, pos)
+    lat = _row_scatter(cache["latent"], layer, latent_new, pos)
     out, f3 = _mla_attend(
-        q_full, scale, lat, p, cfg, ctx, B, 1, decode_len=pos + 1)
+        q_full, scale, cache_layer(lat, layer), p, cfg, ctx, B, 1,
+        decode_len=pos + 1)
     return out, {"latent": lat}, or_flags(f1, f2, f3)
 
 
-def mla_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, valid):
+def mla_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, layer,
+               valid):
     """Speculative verify (dense MLA): the draft window's latents land
     behind the committed prefix (drop-safe window scatter) and each
     query attends its own causal prefix (see gqa_verify)."""
@@ -594,19 +623,21 @@ def mla_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache, valid):
     q_full, scale, f1 = _mla_q(x, p, cfg, ctx, positions)
     c_kv, k_pe, f2 = _mla_latent_kv(x, p, cfg, ctx, positions)
     latent_new = jnp.concatenate([c_kv, k_pe], axis=-1)  # (B, T, c+dr)
-    lat = _window_scatter(cache["latent"], latent_new, pos, valid)
+    lat = _window_scatter(cache["latent"], layer, latent_new, pos, valid)
     out, f3 = _mla_attend(
-        q_full, scale, lat, p, cfg, ctx, B, T, verify_len=pos + 1)
+        q_full, scale, cache_layer(lat, layer), p, cfg, ctx, B, T,
+        verify_len=pos + 1)
     return out, {"latent": lat}, or_flags(f1, f2, f3)
 
 
 def mla_paged_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
-                      cache, tables, lengths, starts=None):
-    """Paged MLA prefill: latent rows scatter into the (NB, BS, c+dr)
-    pool via the admission batch's block tables.  ``starts``: prefix-
-    sharing suffix path — suffix latents land behind the resident shared
-    prefix and attention runs over the gathered logical latent buffer
-    with per-row causal offsets (see gqa_paged_prefill)."""
+                      cache, layer, tables, lengths, starts=None):
+    """Paged MLA prefill: latent rows scatter into entry ``layer`` of the
+    stacked (R, NB, BS, c+dr) pool via the admission batch's block
+    tables.  ``starts``: prefix-sharing suffix path — suffix latents land
+    behind the resident shared prefix and attention runs over the
+    gathered logical latent buffer with per-row causal offsets (see
+    gqa_paged_prefill)."""
     from repro.serve.paged_cache import paged_gather, paged_scatter_prefill
 
     B, L, _ = x.shape
@@ -617,18 +648,18 @@ def mla_paged_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, positions,
         out, f3 = _mla_attend(
             q_full, scale, latent, p, cfg, ctx, B, L, lengths=lengths)
         new_latent = paged_scatter_prefill(
-            cache["latent"], latent, tables, lengths)
+            cache["latent"], layer, latent, tables, lengths)
     else:
         new_latent = paged_scatter_prefill(
-            cache["latent"], latent, tables, lengths, starts=starts)
+            cache["latent"], layer, latent, tables, lengths, starts=starts)
         out, f3 = _mla_attend(
-            q_full, scale, paged_gather(new_latent, tables), p, cfg, ctx,
-            B, L, lengths=starts + lengths, q_offset=starts)
+            q_full, scale, paged_gather(new_latent, layer, tables),
+            p, cfg, ctx, B, L, lengths=starts + lengths, q_offset=starts)
     return out, {"latent": new_latent}, or_flags(f1, f2, f3)
 
 
 def mla_paged_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
-                     tables):
+                     layer, tables):
     """Paged MLA decode: scatter the new latent at the cursor's block,
     gather the slot's blocks, attend with per-row length masking."""
     from repro.serve.paged_cache import paged_gather, paged_scatter_decode
@@ -639,16 +670,16 @@ def mla_paged_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
     q_full, scale, f1 = _mla_q(x, p, cfg, ctx, positions)
     c_kv, k_pe, f2 = _mla_latent_kv(x, p, cfg, ctx, positions)
     latent_new = jnp.concatenate([c_kv, k_pe], axis=-1)  # (B, 1, c+dr)
-    lat = paged_scatter_decode(cache["latent"], latent_new[:, 0], tables,
-                               pos)
+    lat = paged_scatter_decode(cache["latent"], layer, latent_new[:, 0],
+                               tables, pos)
     out, f3 = _mla_attend(
-        q_full, scale, paged_gather(lat, tables), p, cfg, ctx, B, 1,
-        decode_len=pos + 1)
+        q_full, scale, paged_gather(lat, layer, tables), p, cfg, ctx,
+        B, 1, decode_len=pos + 1)
     return out, {"latent": lat}, or_flags(f1, f2, f3)
 
 
 def mla_paged_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
-                     valid, tables):
+                     layer, valid, tables):
     """Paged speculative verify (MLA): draft latents scatter behind the
     committed prefix via the block tables, then every query attends the
     gathered logical latent buffer with its own per-query mask."""
@@ -660,11 +691,11 @@ def mla_paged_verify(x, p, cfg: ModelConfig, ctx: LayerCtx, pos, cache,
     q_full, scale, f1 = _mla_q(x, p, cfg, ctx, positions)
     c_kv, k_pe, f2 = _mla_latent_kv(x, p, cfg, ctx, positions)
     latent_new = jnp.concatenate([c_kv, k_pe], axis=-1)  # (B, T, c+dr)
-    lat = paged_scatter_prefill(cache["latent"], latent_new, tables,
+    lat = paged_scatter_prefill(cache["latent"], layer, latent_new, tables,
                                 valid, starts=pos)
     out, f3 = _mla_attend(
-        q_full, scale, paged_gather(lat, tables), p, cfg, ctx, B, T,
-        verify_len=pos + 1)
+        q_full, scale, paged_gather(lat, layer, tables), p, cfg, ctx,
+        B, T, verify_len=pos + 1)
     return out, {"latent": lat}, or_flags(f1, f2, f3)
 
 

@@ -10,6 +10,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.analysis.markers import coverage_scope
 from repro.core.faults import FaultSpec
@@ -88,6 +89,10 @@ class LayerCtx:
     fault: ModelFault | None = None
     layer_idx: jnp.ndarray | None = None   # traced global layer index
     hints: ShardingHints | None = None
+    # the one device the serving cache lives on, whose storage layout the
+    # layer loop's carried cache keeps (keep_storage_layout); None when
+    # the cache is sharded over a mesh or there is none
+    cache_device: jax.Device | None = None
     # static prefix for plan-facing site tags ("enc." inside the whisper
     # encoder stack) so the coverage auditor can tell encoder GEMMs from
     # identically-shaped decoder ones
@@ -176,6 +181,52 @@ def apply_rope(x, cos, sin, rot: int):
     s = sin[:, :, None, :].astype(x.dtype)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return jnp.concatenate([out, xp], axis=-1) if rot < x.shape[-1] else out
+
+
+# ------------------------------------------------------------ stacked cache
+# Cache leaves stay stacked over a segment's repeats, (R, ...), and ride
+# the layer scan as carry: each layer reads its own entry where it is
+# consumed and writes only the rows it produces, so XLA updates the
+# loop-carried buffer in place instead of copying a whole layer out of
+# the stack and back every step.
+
+def storage_layout(leaf, device):
+    """The layout ``device`` stores an array of ``leaf``'s shape and dtype
+    in: what the engine's cache arrays arrive in.  (A TPU v5e keeps a
+    [.., S, 32, 64] bf16 cache with positions minor, because a 64-wide
+    minor dim would be padded to 128.)"""
+    return Layout.from_pjrt_layout(
+        device.client.get_default_layout(leaf.dtype, leaf.shape, device))
+
+
+def keep_storage_layout(tree, device):
+    """Pin loop-carried cache leaves to ``device``'s storage layout.
+    Otherwise the compiler may give the loop state the layout its readers
+    prefer (a dot's, or a padded one) and relayout the whole cache on the
+    way into and out of the layer loop: a copy of every layer per call,
+    and one more cache-sized buffer.
+
+    ``device`` is ``LayerCtx.cache_device``, set by the executor that
+    places the cache.  ``None`` (a cache sharded over a mesh: the SPMD
+    partitioner cannot split the pin) leaves the layout to the compiler."""
+    if device is None:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda a: with_layout_constraint(a, storage_layout(a, device)), tree)
+
+
+def cache_layer(leaf, layer):
+    """Entry ``layer`` of a stacked cache leaf (R, ...) -> (...)."""
+    return jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+
+
+def cache_write(leaf, layer, new):
+    """Write ``new`` at the origin of entry ``layer`` of a stacked leaf:
+    ``new`` may be shorter than the entry along any dim (a prompt of L
+    tokens into an S-deep row)."""
+    start = (layer,) + (0,) * new.ndim
+    return jax.lax.dynamic_update_slice(
+        leaf, new[None].astype(leaf.dtype), start)
 
 
 # ---------------------------------------------------------------- attention

@@ -17,7 +17,14 @@ import jax.numpy as jnp
 
 from repro.analysis.markers import coverage_scope
 from repro.configs.base import ModelConfig
-from repro.models.layers import LayerCtx, dense, gated_rms_norm, or_flags
+from repro.models.layers import (
+    LayerCtx,
+    cache_layer,
+    cache_write,
+    dense,
+    gated_rms_norm,
+    or_flags,
+)
 
 F32 = jnp.float32
 
@@ -169,9 +176,10 @@ def mamba_forward(x, p, cfg: ModelConfig, ctx: LayerCtx):
     return out, or_flags(f1, f2)
 
 
-def mamba_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, cache,
+def mamba_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, cache, layer,
                   slots=None, lengths=None):
-    """Prefill: full-sequence forward + final (conv, ssm) states.
+    """Prefill: full-sequence forward + final (conv, ssm) states, written
+    into entry ``layer`` of the stacked (R, B, ...) state leaves.
 
     ``slots``/``lengths`` (continuous-batching path): x is the admission
     batch (A, L, D) padded to a common L.  Padded positions are masked out
@@ -224,15 +232,14 @@ def mamba_prefill(x, p, cfg: ModelConfig, ctx: LayerCtx, cache,
     conv_x_state = conv_x_state.astype(cache["conv_x"].dtype)
     conv_bc_state = conv_bc_state.astype(cache["conv_bc"].dtype)
     S_final = S_final.astype(cache["ssm"].dtype)
+    states = {"conv_x": conv_x_state, "conv_bc": conv_bc_state,
+              "ssm": S_final}
     if slots is None:
-        new_cache = {
-            "conv_x": conv_x_state, "conv_bc": conv_bc_state, "ssm": S_final}
+        new_cache = {k: cache_write(cache[k], layer, st)
+                     for k, st in states.items()}
     else:
-        new_cache = {
-            "conv_x": cache["conv_x"].at[slots].set(conv_x_state),
-            "conv_bc": cache["conv_bc"].at[slots].set(conv_bc_state),
-            "ssm": cache["ssm"].at[slots].set(S_final),
-        }
+        new_cache = {k: cache[k].at[layer, slots].set(st)
+                     for k, st in states.items()}
     return out, new_cache, or_flags(f1, f2)
 
 
@@ -246,10 +253,10 @@ def _conv_step(state, new, w, b):
         return out, window[:, 1:, :]
 
 
-def mamba_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, cache):
-    """One-token recurrent step.  x: (B, 1, D).  Serves the dense AND
-    paged engines alike (per-slot constant-size state; see
-    mamba_prefill)."""
+def mamba_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, cache, layer):
+    """One-token recurrent step.  x: (B, 1, D); reads and rewrites entry
+    ``layer`` of the stacked state leaves.  Serves the dense AND paged
+    engines alike (per-slot constant-size state; see mamba_prefill)."""
     Bsz = x.shape[0]
     H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     z, xs, Bm, Cm, dt, f1 = _project_in(x, p, cfg, ctx)
@@ -257,16 +264,18 @@ def mamba_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, cache):
     bc_in = jnp.concatenate([Bm[:, 0], Cm[:, 0]], axis=-1)
 
     xs2, new_conv_x = _conv_step(
-        cache["conv_x"], xs, p["conv_x_w"], p["conv_x_b"])
+        cache_layer(cache["conv_x"], layer), xs, p["conv_x_w"],
+        p["conv_x_b"])
     bc2, new_conv_bc = _conv_step(
-        cache["conv_bc"], bc_in, p["conv_bc_w"], p["conv_bc_b"])
+        cache_layer(cache["conv_bc"], layer), bc_in, p["conv_bc_w"],
+        p["conv_bc_b"])
     Bm2, Cm2 = bc2[..., :N], bc2[..., N:]
 
     dt2 = jax.nn.softplus(dt.astype(F32) + p["dt_bias"])   # (B, H)
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt2 * A[None, :])                         # (B, H)
     xh = xs2.reshape(Bsz, H, P)
-    S = cache["ssm"].astype(F32)                           # (B,H,P,N)
+    S = cache_layer(cache["ssm"], layer).astype(F32)       # (B,H,P,N)
     with coverage_scope("ssm_scan"):
         S = S * dA[:, :, None, None] + jnp.einsum(
             "bh,bn,bhp->bhpn", dt2, Bm2, xh, preferred_element_type=F32)
@@ -276,11 +285,9 @@ def mamba_decode(x, p, cfg: ModelConfig, ctx: LayerCtx, cache):
     y = y.reshape(Bsz, 1, cfg.d_inner).astype(x.dtype)
     y = gated_rms_norm(y, z[:, None, :], p["out_norm"], cfg.norm_eps)
     out, f2 = dense(y, p["out_proj"], ctx, "ssm_out", tag="ssm.out")
-    new_cache = {
-        "conv_x": new_conv_x.astype(cache["conv_x"].dtype),
-        "conv_bc": new_conv_bc.astype(cache["conv_bc"].dtype),
-        "ssm": S.astype(cache["ssm"].dtype),
-    }
+    states = {"conv_x": new_conv_x, "conv_bc": new_conv_bc, "ssm": S}
+    new_cache = {k: cache_write(cache[k], layer, st)
+                 for k, st in states.items()}
     return out, new_cache, or_flags(f1, f2)
 
 

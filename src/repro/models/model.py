@@ -24,7 +24,16 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import mamba as mb
 from repro.models import moe as moe_mod
-from repro.models.layers import LayerCtx, dense, mlp, norm, or_flags
+from repro.models.layers import (
+    LayerCtx,
+    cache_layer,
+    cache_write,
+    dense,
+    keep_storage_layout,
+    mlp,
+    norm,
+    or_flags,
+)
 
 F32 = jnp.float32
 
@@ -153,10 +162,15 @@ def init_layer_cache(cfg: ModelConfig, tag: str, batch: int, max_len: int,
 def apply_layer(
     x, lp, tag: str, cfg: ModelConfig, ctx: LayerCtx, positions,
     mode: str, cache, pos, mem, causal: bool = True,
-    slots=None, lengths=None, tables=None, prefix_lens=None,
+    slots=None, lengths=None, tables=None, prefix_lens=None, layer=0,
 ):
     """One transformer/mamba layer.  mode: full | prefill | decode |
     verify.
+    ``cache`` (every mode but full): this unit position's cache leaves,
+    STACKED over the segment's repeats (R, ...), and ``layer`` the traced
+    index of this layer's entry in them.  Each writer writes only the
+    rows it produces into that entry and each reader reads the entry
+    where it is consumed; the returned cache is the updated stack.
     ``pos`` (decode/verify): scalar or (B,) per-slot cursor vector.
     ``verify`` (speculative decoding): x is (B, T, D) — each row's last
     committed token plus its draft window — and ``lengths`` carries the
@@ -173,7 +187,9 @@ def apply_layer(
     path (paged or dense).  Attention layers only: SSM recurrence state
     is not a pure function of resident KV, so both are gated off for
     hybrid stacks at the engine.
-    Returns (x, new_cache, flag, aux)."""
+    Returns (x, new_cache, flag, aux); ``new_cache`` holds only the
+    entries this mode writes (cross-attention K/V is read-only in
+    decode)."""
     mixer, ffn, cross = tag.split(":")
     flags = []
     aux = jnp.zeros((), F32)
@@ -198,12 +214,12 @@ def apply_layer(
         elif mode == "prefill":
             if tables is not None:
                 a, nc, f = pre(h, lp["mixer"], cfg, ctx, positions,
-                               cache["attn"], tables, lengths,
+                               cache["attn"], layer, tables, lengths,
                                starts=prefix_lens)
             else:
                 a, nc, f = pre(h, lp["mixer"], cfg, ctx, positions,
-                               cache["attn"], slots=slots, lengths=lengths,
-                               starts=prefix_lens)
+                               cache["attn"], layer, slots=slots,
+                               lengths=lengths, starts=prefix_lens)
             new_cache["attn"] = nc
         elif mode == "verify":
             ver = (attn.gqa_verify if mixer == "attn" else attn.mla_verify)
@@ -211,17 +227,18 @@ def apply_layer(
                 ver = (attn.gqa_paged_verify if mixer == "attn"
                        else attn.mla_paged_verify)
                 a, nc, f = ver(h, lp["mixer"], cfg, ctx, pos,
-                               cache["attn"], lengths, tables)
+                               cache["attn"], layer, lengths, tables)
             else:
                 a, nc, f = ver(h, lp["mixer"], cfg, ctx, pos,
-                               cache["attn"], lengths)
+                               cache["attn"], layer, lengths)
             new_cache["attn"] = nc
         else:
             if tables is not None:
                 a, nc, f = dec(h, lp["mixer"], cfg, ctx, pos, cache["attn"],
-                               tables)
+                               layer, tables)
             else:
-                a, nc, f = dec(h, lp["mixer"], cfg, ctx, pos, cache["attn"])
+                a, nc, f = dec(h, lp["mixer"], cfg, ctx, pos, cache["attn"],
+                               layer)
             new_cache["attn"] = nc
     else:
         # mamba state is constant-size per request (conv window + SSD
@@ -238,12 +255,12 @@ def apply_layer(
             a, f = mb.mamba_forward(h, lp["mixer"], cfg, ctx)
         elif mode == "prefill":
             a, nc, f = mb.mamba_prefill(h, lp["mixer"], cfg, ctx,
-                                        cache["attn"],
+                                        cache["attn"], layer,
                                         slots=slots, lengths=lengths)
             new_cache["attn"] = nc
         else:
             a, nc, f = mb.mamba_decode(h, lp["mixer"], cfg, ctx,
-                                       cache["attn"])
+                                       cache["attn"], layer)
             new_cache["attn"] = nc
     x = x + a
     flags.append(f)
@@ -251,21 +268,21 @@ def apply_layer(
     if cross == "1":
         h = norm(x, lp["cross_norm"], cfg.norm, cfg.norm_eps)
         if mode == "decode":
-            ck, cv = cache["cross"]["k"], cache["cross"]["v"]
+            ck = cache_layer(cache["cross"]["k"], layer)
+            cv = cache_layer(cache["cross"]["v"], layer)
             fkv = jnp.zeros((), bool)
-            new_cache["cross"] = cache["cross"]
         else:
             ck, cv, fkv = attn.cross_kv(mem, lp["cross"], cfg, ctx)
             if mode == "prefill":
-                ckd = ck.astype(cache["cross"]["k"].dtype)
-                cvd = cv.astype(cache["cross"]["v"].dtype)
                 if slots is None:
-                    new_cache["cross"] = {"k": ckd, "v": cvd}
+                    new_cache["cross"] = {
+                        n: cache_write(cache["cross"][n], layer, new)
+                        for n, new in (("k", ck), ("v", cv))}
                 else:
                     new_cache["cross"] = {
-                        "k": cache["cross"]["k"].at[slots].set(ckd),
-                        "v": cache["cross"]["v"].at[slots].set(cvd),
-                    }
+                        n: cache["cross"][n].at[layer, slots].set(
+                            new.astype(cache["cross"][n].dtype))
+                        for n, new in (("k", ck), ("v", cv))}
         a, f = attn.cross_forward(h, ck, cv, lp["cross"], cfg, ctx)
         gate = jnp.tanh(lp["cross_gate"]).astype(x.dtype)
         x = x + gate * a
@@ -286,6 +303,12 @@ def apply_layer(
 
 # ---------------------------------------------------------------- stacks
 
+def _read_only(mode: str) -> tuple:
+    """Layer-cache entries ``mode`` only reads: they stay out of the layer
+    scan's carry, so they are neither written nor copied."""
+    return ("cross",) if mode == "decode" else ()
+
+
 def run_stack(
     x, segments_params, plan, cfg: ModelConfig, ctx: LayerCtx, positions,
     mode: str, caches, pos, mem, causal: bool = True, remat: bool = False,
@@ -297,59 +320,66 @@ def run_stack(
     thread the continuous-batching prefill path, ``tables`` the paged
     block-table path, and ``prefix_lens`` the prefix-sharing suffix
     prefill (see apply_layer).
+
+    Each segment is one ``lax.scan`` over its stacked params (a
+    single-unit segment runs the same body once, at index 0).  The
+    segment's stacked cache leaves (R, ...) travel as part of the scan's
+    CARRY, never as its xs/ys: layer ``rep`` writes only its new rows
+    into entry ``rep`` of the carried buffers, which XLA updates in
+    place, and reads that entry where attention consumes it.  Entries a
+    mode only reads (``_read_only``) are closed over instead.
     Returns (x, new_caches, flag, aux)."""
     flag = jnp.zeros((), bool)
     aux = jnp.zeros((), F32)
+    ro_names = _read_only(mode)
     new_caches = []
     offset = layer_offset
     for si, seg in enumerate(plan):
         sp = segments_params[si]
-        sc = caches[si] if caches is not None else None
         p = len(seg.unit)
         seg_off = offset
+        if caches is None:
+            written, ro = None, None
+        else:
+            written = {q: {n: c for n, c in lc.items() if n not in ro_names}
+                       for q, lc in caches[si].items()}
+            ro = {q: {n: c for n, c in lc.items() if n in ro_names}
+                  for q, lc in caches[si].items()}
 
-        def unit_body(carry, xs, _unit=seg.unit, _off=seg_off, _p=p):
-            xx, fl, au = carry
-            if sc is not None:
-                up, uc, rep = xs
-            else:
-                up, rep = xs
-                uc = None
-            new_uc = {}
+        def unit_body(carry, xs, _unit=seg.unit, _off=seg_off, _p=p,
+                      _ro=ro):
+            xx, fl, au, uc = carry
+            uc = keep_storage_layout(uc, ctx.cache_device)
+            up, rep = xs
             for q, tag in enumerate(_unit):
                 idx = _off + rep * _p + q
                 lctx = ctx.with_layer(jnp.asarray(idx, jnp.int32))
+                key = f"pos{q}"
+                lc = None if uc is None else {**uc[key], **_ro[key]}
                 xx, ncq, f, a = apply_layer(
-                    xx, up[f"pos{q}"], tag, cfg, lctx, positions, mode,
-                    uc[f"pos{q}"] if uc is not None else None, pos, mem,
-                    causal=causal, slots=slots, lengths=lengths,
-                    tables=tables, prefix_lens=prefix_lens,
+                    xx, up[key], tag, cfg, lctx, positions, mode, lc, pos,
+                    mem, causal=causal, slots=slots, lengths=lengths,
+                    tables=tables, prefix_lens=prefix_lens, layer=rep,
                 )
-                new_uc[f"pos{q}"] = ncq
+                if uc is not None:
+                    uc = {**uc, key: ncq}
                 fl = jnp.logical_or(fl, f)
                 au = au + a
-            return (xx, fl, au), new_uc if sc is not None else None
+            return ((xx, fl, au, keep_storage_layout(uc, ctx.cache_device)),
+                    None)
 
         body = jax.checkpoint(unit_body) if remat else unit_body
-
+        carry = (x, flag, aux, written)
         if seg.repeats == 1:
-            # single unit: apply directly (no scan) with unstacked params
+            # single unit: apply directly (no scan) at index 0
             sp1 = jax.tree_util.tree_map(lambda a: a[0], sp)
-            sc1 = (
-                jax.tree_util.tree_map(lambda a: a[0], sc)
-                if sc is not None else None
-            )
-            xs = (sp1, sc1, jnp.zeros((), jnp.int32)) if sc is not None \
-                else (sp1, jnp.zeros((), jnp.int32))
-            (x, flag, aux), nc = body((x, flag, aux), xs)
-            new_caches.append(
-                jax.tree_util.tree_map(lambda a: a[None], nc)
-                if nc is not None else None)
+            carry, _ = body(carry, (sp1, jnp.zeros((), jnp.int32)))
         else:
             reps = jnp.arange(seg.repeats, dtype=jnp.int32)
-            xs = (sp, sc, reps) if sc is not None else (sp, reps)
-            (x, flag, aux), nc = jax.lax.scan(body, (x, flag, aux), xs)
-            new_caches.append(nc)
+            carry, _ = jax.lax.scan(body, carry, (sp, reps))
+        x, flag, aux, written = carry
+        if caches is not None:
+            new_caches.append({q: {**written[q], **ro[q]} for q in written})
         offset += p * seg.repeats
     return x, new_caches, flag, aux
 

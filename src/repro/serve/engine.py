@@ -372,7 +372,8 @@ class ServeEngine:
             level_cfgs = (abft,)
         self._level_abft = level_cfgs
         self._level_ctx = tuple(
-            LayerCtx(abft=c, hints=self.executor.hints)
+            LayerCtx(abft=c, hints=self.executor.hints,
+                     cache_device=self.executor.cache_device)
             for c in level_cfgs)
         self.protection_level = 0
         self.ctx = self._level_ctx[0]

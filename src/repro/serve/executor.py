@@ -65,6 +65,10 @@ class LocalExecutor:
         self.dtype = dtype
         self.dtype_bytes = jnp.dtype(dtype).itemsize
         self.hints = hints
+        # the one device the cache lands on, whose storage layout the
+        # layer loop keeps (LayerCtx.cache_device); a mesh-sharded cache
+        # leaves its layout to the partitioner
+        self.cache_device = jax.devices()[0] if self.mesh is None else None
         self.cache = None
         self.keys = None
 

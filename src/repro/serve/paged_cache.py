@@ -464,10 +464,10 @@ def init_paged_mamba_cache(cfg: ModelConfig, slots: int, dtype) -> dict:
 # Sentinel-safe scatter/gather between logical (row, position) coordinates
 # and the physical pool.  Shared by the GQA and MLA paged paths.
 
-def paged_scatter_prefill(pool, new, tables, lengths, starts=None):
-    """Write an admission batch into the pool.
+def paged_scatter_prefill(pool, layer, new, tables, lengths, starts=None):
+    """Write an admission batch into entry ``layer`` of a stacked pool.
 
-    pool: (NB, BS, ...); new: (A, L, ...) padded to a common L;
+    pool: (R, NB, BS, ...); new: (A, L, ...) padded to a common L;
     tables: (A, W) int32 rows (sentinel-padded); lengths: (A,) valid
     token counts of ``new``.  Positions >= lengths[a] are routed to the
     sentinel and dropped.
@@ -476,7 +476,7 @@ def paged_scatter_prefill(pool, new, tables, lengths, starts=None):
     the prefix-sharing suffix prefill writes ``new[a, t]`` at logical
     position ``starts[a] + t`` (the shared prefix already lives in the
     pool).  ``None`` keeps the from-zero fast path bit-for-bit."""
-    nb, bs = pool.shape[0], pool.shape[1]
+    nb, bs = pool.shape[1], pool.shape[2]
     A, L = new.shape[0], new.shape[1]
     t = jnp.arange(L, dtype=jnp.int32)
     valid = t[None, :] < lengths[:, None]
@@ -489,34 +489,34 @@ def paged_scatter_prefill(pool, new, tables, lengths, starts=None):
                                   mode="clip")
         off = logical % bs
     blk = jnp.where(valid, blk, nb)                    # force-drop padding
-    return pool.at[blk, off].set(new.astype(pool.dtype), mode="drop")
+    return pool.at[layer, blk, off].set(new.astype(pool.dtype), mode="drop")
 
 
-def paged_scatter_decode(pool, new, tables, pos):
-    """Write one new entry per slot at its own cursor.
+def paged_scatter_decode(pool, layer, new, tables, pos):
+    """Write one new entry per slot at its own cursor, in entry ``layer``.
 
-    pool: (NB, BS, ...); new: (B, ...); tables: (B, W); pos: (B,) int32.
-    Inactive/freed slots carry sentinel tables, so their writes drop —
-    no activity mask is needed (the table IS the guard)."""
-    bs = pool.shape[1]
+    pool: (R, NB, BS, ...); new: (B, ...); tables: (B, W); pos: (B,)
+    int32.  Inactive/freed slots carry sentinel tables, so their writes
+    drop — no activity mask is needed (the table IS the guard)."""
+    bs = pool.shape[2]
     blk = jnp.take_along_axis(
         tables, (pos[:, None] // bs).astype(jnp.int32), axis=1)[:, 0]
     off = pos % bs
-    return pool.at[blk, off].set(new.astype(pool.dtype), mode="drop")
+    return pool.at[layer, blk, off].set(new.astype(pool.dtype), mode="drop")
 
 
-def paged_gather(pool, tables):
-    """Materialize per-slot contiguous KV from the pool.
+def paged_gather(pool, layer, tables):
+    """Materialize per-slot contiguous KV from entry ``layer`` of a
+    stacked pool.
 
-    pool: (NB, BS, ...); tables: (B, W) -> (B, W*BS, ...).  Sentinel
+    pool: (R, NB, BS, ...); tables: (B, W) -> (B, W*BS, ...).  Sentinel
     blocks read as zeros; callers mask by per-row length before softmax.
     (The Pallas paged flash_decode skips this materialization and indexes
     the pool directly via the block table — this is the XLA reference
     path.)"""
-    bs = pool.shape[1]
     B, W = tables.shape
-    g = jnp.take(pool, tables, axis=0, mode="fill", fill_value=0)
-    return g.reshape((B, W * bs) + pool.shape[2:])
+    g = pool.at[layer, tables].get(mode="fill", fill_value=0)
+    return g.reshape((B, W * pool.shape[2]) + pool.shape[3:])
 
 
 def pytree_bytes(tree) -> int:

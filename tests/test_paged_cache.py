@@ -117,14 +117,14 @@ def test_pool_free_list_reuse_after_eviction():
 
 def test_scatter_gather_roundtrip_and_sentinel_drop():
     bp = BlockPool(num_blocks=5, block_size=4, slots=2, table_width=3)
-    pool = jnp.zeros((5, 4, 2), jnp.float32)
+    pool = jnp.zeros((1, 5, 4, 2), jnp.float32)      # one layer's entry
     lens = np.array([6, 3], np.int32)
     for s in range(2):
         assert bp.try_alloc(s, int(lens[s]))
     new = jnp.arange(2 * 8 * 2, dtype=jnp.float32).reshape(2, 8, 2) + 1.0
     pool = paged_scatter_prefill(
-        pool, new, bp.device_tables(), jnp.asarray(lens))
-    g = paged_gather(pool, bp.device_tables())      # (2, 12, 2)
+        pool, 0, new, bp.device_tables(), jnp.asarray(lens))
+    g = paged_gather(pool, 0, bp.device_tables())   # (2, 12, 2)
     for s in range(2):
         np.testing.assert_array_equal(
             np.asarray(g[s, : lens[s]]), np.asarray(new[s, : lens[s]]))
@@ -136,8 +136,8 @@ def test_scatter_gather_roundtrip_and_sentinel_drop():
     bp.free_slot(1)
     step = jnp.full((2, 2), 7.0)
     pool2 = paged_scatter_decode(
-        pool, step, bp.device_tables(), jnp.asarray([6, 3], jnp.int32))
-    g2 = paged_gather(pool2, bp.device_tables())
+        pool, 0, step, bp.device_tables(), jnp.asarray([6, 3], jnp.int32))
+    g2 = paged_gather(pool2, 0, bp.device_tables())
     np.testing.assert_array_equal(np.asarray(g2[0, 6]), [7.0, 7.0])
     assert float(jnp.sum(pool2)) == pytest.approx(
         float(jnp.sum(pool)) + 14.0)    # only slot 0's write landed
@@ -156,17 +156,17 @@ def test_paged_flash_decode_matches_reference():
     v_new = jnp.asarray(rng.standard_normal((B, 24, KV, D)), jnp.float32)
     tables = bp.device_tables()
     pool_k = paged_scatter_prefill(
-        jnp.zeros((NB, BS, KV, D), jnp.float32), k_new, tables,
+        jnp.zeros((1, NB, BS, KV, D), jnp.float32), 0, k_new, tables,
         jnp.asarray(lens))
     pool_v = paged_scatter_prefill(
-        jnp.zeros((NB, BS, KV, D), jnp.float32), v_new, tables,
+        jnp.zeros((1, NB, BS, KV, D), jnp.float32), 0, v_new, tables,
         jnp.asarray(lens))
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
 
     ref = decode_attention(
-        q, paged_gather(pool_k, tables), paged_gather(pool_v, tables),
+        q, paged_gather(pool_k, 0, tables), paged_gather(pool_v, 0, tables),
         jnp.asarray(lens))
-    out, chk = flash_decode_paged(q, pool_k, pool_v, tables,
+    out, chk = flash_decode_paged(q, pool_k[0], pool_v[0], tables,
                                   jnp.asarray(lens))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)

@@ -13,6 +13,7 @@ but never read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,3 +96,103 @@ def test_flash_decode_paged_compiles(spec):
         spec((B * W, block, KV_HEADS, HEAD_DIM)),
         spec((B * W, block, KV_HEADS, HEAD_DIM)),
         spec((B, W), jnp.int32), spec((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("entry", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("chips", [1, 4])
+def test_layer_loop_keeps_cache_storage_layout(topo, entry, chips):
+    """The stacked cache rides the layer loop in the layout the chip
+    stores it in.  With 64-wide heads a v5e keeps positions minor
+    (unpadded); a scatter into the carried cache, or a reader's
+    preference, would otherwise pull the whole loop state into another
+    layout (padded 2x here) and relayout the cache into and out of the
+    loop on every call.
+
+    One chip pins the carry to that layout (``LayerCtx.cache_device``):
+    the program holds the cache in one layout and copies each leaf whole
+    once, the entry copy of the input, which is not donated.  A 4-way
+    ``model`` mesh, as ``MeshExecutor`` serves, is left unpinned (the
+    partitioner would gather the whole cache onto every device to apply
+    the pin): its decode keeps the storage layout, and its chunk program
+    relayouts each device's shard into the loop and back, two copies a
+    leaf.  Neither ever holds more than a shard of the cache."""
+    import numpy as np
+    from jax.experimental.layout import Layout
+    from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+    from repro.configs import get_config, scaled_down
+    from repro.core import ABFTConfig, Scheme
+    from repro.distributed.mesh import make_hints
+    from repro.distributed.sharding import (
+        cache_specs,
+        make_sharding,
+        param_specs,
+    )
+    from repro.models import ModelFault, build_model
+    from repro.models.layers import LayerCtx
+    from repro.serve.runner import ModelRunner
+
+    dev = topo.devices[0]
+    cfg = scaled_down(get_config("llama3.2-1b"), n_layers=4, d_model=256,
+                      n_heads=4, n_kv_heads=4, head_dim=HEAD_DIM,
+                      d_ff=512, vocab_size=512)
+    model = build_model(cfg)
+    B, S, L = 8, 512, 64
+    params = jax.eval_shape(
+        lambda k: model.init_params(k, BF16), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(B, S, BF16))
+    abft = ABFTConfig(scheme=Scheme.AUTO, use_pallas=False)
+    if chips == 1:
+        one = SingleDeviceSharding(dev)
+        p_sh = jax.tree_util.tree_map(lambda _: one, params)
+        c_sh = jax.tree_util.tree_map(lambda _: one, cache)
+        rep = one
+        ctx = LayerCtx(abft=abft, cache_device=dev)
+    else:
+        mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+        p_sh = make_sharding(mesh, param_specs(cfg, params, mesh))
+        c_sh = make_sharding(mesh, cache_specs(cfg, cache, mesh, B))
+        rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+        ctx = LayerCtx(abft=abft, hints=make_hints(cfg, mesh))
+    runner = ModelRunner(model, ctx)
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            tree, shardings)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params, cache = placed(params, p_sh), placed(cache, c_sh)
+    fault = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), jax.eval_shape(ModelFault.none))
+    i32 = lambda *s: spec(s, jnp.int32)                  # noqa: E731
+    keys = lambda n: spec((n, 2), jnp.uint32)            # noqa: E731
+    if entry == "decode":
+        args = (params, i32(B, 1), cache, i32(B), spec((B,), jnp.bool_),
+                keys(B), None, fault)
+    else:
+        args = (params, i32(1, L), cache, i32(1), i32(1), keys(1), None,
+                i32(1), spec((1,), jnp.bool_), fault)
+    hlo = getattr(runner, entry).lower(*args).compile().as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    leaf = leaves[0]
+    shard = leaf.sharding.shard_shape(leaf.shape)
+    stored = Layout.from_pjrt_layout(
+        dev.client.get_default_layout(leaf.dtype, shard, dev))
+    dims = ",".join(map(str, shard))
+    want = ",".join(map(str, stored.major_to_minor[::-1]))
+    seen = set(re.findall(rf"\[{dims}\]\{{([\d,]+)", hlo))
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(", hlo)
+    relayout = chips == 4 and entry == "prefill_chunk"
+    if not relayout:
+        assert seen == {want}, (
+            f"cache shard {shard} stored {{{want}}} but the program also "
+            f"holds it in {seen - {want}}")
+    assert len(copies) <= (2 if relayout else 1) * len(leaves), (
+        f"{len(copies)} whole-cache copies of {len(leaves)} cache leaves")
+    if chips == 4:
+        full = ",".join(map(str, leaf.shape))
+        assert f"[{full}]" not in hlo, "the sharded cache is gathered whole"
