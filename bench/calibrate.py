@@ -42,7 +42,8 @@ def main(argv=None) -> int:
     cell = spec.cell(args.workload)
     cfg, settings = cell.config, cell.settings
     seeds = [int(s) for s in args.seeds.split(",")]
-    drv, w_dev, _, _ = harness.setup(cell, seeds[0])
+    drv, w_dev, devs, _ = harness.setup(cell, seeds[0])
+    mesh = harness.cell_mesh(devs)
     ref = spec.reference(cfg["architecture"])
     rows = []
     for i, seed in enumerate(seeds):
@@ -50,9 +51,9 @@ def main(argv=None) -> int:
             drv.engine.executor.params = None
             w_dev = None
             gc.collect()
-            w_dev = weights.make(cfg, seed)
+            w_dev = weights.make(cfg, seed, mesh)
             drv.engine.executor.params = program.params(drv.engine.model,
-                                                        w_dev)
+                                                        cfg, w_dev)
         items = traffic.stream(cell.mix, seed, cfg["vocab_size"],
                                rate=settings.get("rate_per_s"))
         w = drv.window(items, args.seconds, loop=cell.mix["loop"],

@@ -9,6 +9,13 @@ partial prefill at a time).  That keeps every prefill call at one row,
 so the warm-up can compile every shape the window can reach: one chunk
 program per length bucket (multiples of 8 up to the chunk budget) and
 the decode program.
+
+A cell on more than one chip serves over a ``(data=1, model=chips)``
+mesh of the first ``chips`` devices; its weights are made sharded, and
+its device readings come from each of those devices: the fullest one's
+memory, their mean busy time, every one's own under
+``device.per_device``; the per-layer readers see the most idle one's
+ops.
 """
 
 from __future__ import annotations
@@ -66,21 +73,30 @@ def enable_compile_cache() -> str:
 
 
 def device_info(cell, require_tpu: bool) -> tuple:
-    """(device, peaks).  No TPU, too few chips or an unknown chip kind is
-    an error: no number is ever reported for another device."""
+    """(the cell's devices, peaks of one).  No TPU, too few chips or an
+    unknown chip kind is an error: no number is ever reported for
+    another device."""
     import jax
 
     devs = jax.devices()
     dev = devs[0]
-    if require_tpu:
-        if dev.platform != "tpu":
-            raise SystemExit(f"bench: needs a TPU, JAX found "
-                             f"{dev.platform!r}")
-        if len(devs) < cell.chips:
-            raise SystemExit(f"bench: {cell.name} needs {cell.chips} chips, "
-                             f"JAX found {len(devs)}")
-        return dev, spec.peaks(dev.device_kind)
-    return dev, spec.peaks("TPU v5 lite")
+    if require_tpu and dev.platform != "tpu":
+        raise SystemExit(f"bench: needs a TPU, JAX found {dev.platform!r}")
+    if len(devs) < cell.chips:
+        raise SystemExit(f"bench: {cell.name} needs {cell.chips} chips, "
+                         f"JAX found {len(devs)}")
+    kind = dev.device_kind if require_tpu else "TPU v5 lite"
+    return devs[:cell.chips], spec.peaks(kind)
+
+
+def cell_mesh(devs):
+    """The ``(data=1, model=chips)`` mesh over a cell's devices, or None
+    for a cell on one chip."""
+    from jax.sharding import Mesh
+
+    if len(devs) == 1:
+        return None
+    return Mesh(np.array(devs).reshape(1, len(devs)), ("data", "model"))
 
 
 @dataclasses.dataclass
@@ -331,18 +347,23 @@ def traced(w: Window, cell, cfg: dict, peaks: dict, settings: dict,
                         dirs_exist_ok=True)
     shutil.rmtree(w.trace_dir, ignore_errors=True)
     window_ns = tr["window_ns"]
-    planes = sorted(tr["devices"])
+    planes = sorted(tr["devices"], key=lambda p: (len(p), p))[:cell.chips]
     if not planes:
         raise RuntimeError("the trace holds no device operations")
-    busy = [trace.busy_ns(tr["devices"][p], window_ns) for p in planes]
-    ops = tr["devices"][planes[0]]
+    devices = {p: tr["devices"][p] for p in planes}
+    busy = {p: trace.busy_ns(ops, window_ns) for p, ops in devices.items()}
+    idlest = min(planes, key=busy.get)
+    ops = devices[idlest]
     gaps = trace.idle_gaps(ops, window_ns)
     first = sum(1 for r in w.requests if r.times
                 and w.trace_t0 <= r.times[0] <= w.trace_t1)
     run = types.SimpleNamespace(
         cell=cell, cfg=cfg, peaks=peaks, settings=settings, window=w,
-        ops=ops, window_s=window_ns / 1e9,
-        busy_s=sum(busy) / len(busy) / 1e9, steps=w.trace_steps,
+        chips=cell.chips, devices=devices, ops=ops,
+        window_s=window_ns / 1e9,
+        busy_s=sum(busy.values()) / len(busy) / 1e9,
+        busy_by_device=[busy[p] / 1e9 for p in planes],
+        steps=w.trace_steps,
         first_tokens=first, compiles=w.compiles,
         queue_waits=[r.admitted_at - r.due for r in w.requests
                      if r.admitted_at is not None])
@@ -351,28 +372,49 @@ def traced(w: Window, cell, cfg: dict, peaks: dict, settings: dict,
     return run, breakdown, tr["sample_stats"]
 
 
+def device_record(devs, run_ctx=None) -> dict:
+    """The result's ``device``: the fullest device's peak memory, and with
+    a traced run the devices' mean busy time; each device's own readings
+    under ``per_device``."""
+    per = [{"id": d.id, "memory_peak_bytes": int(
+        (d.memory_stats() or {}).get("peak_bytes_in_use", 0))} for d in devs]
+    out = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs),
+           "memory_peak_bytes": max(p["memory_peak_bytes"] for p in per),
+           "per_device": per}
+    if run_ctx is not None:
+        out.update(busy_s=run_ctx.busy_s, window_s=run_ctx.window_s)
+        for p, b in zip(per, run_ctx.busy_by_device):
+            p.update(busy_s=b, idle_share=100.0 * (1.0 - b
+                                                   / run_ctx.window_s))
+    return out
+
+
 # ------------------------------------------------------------------ run
 def setup(cell, seed: int, require_tpu: bool = True, engine_hook=None):
-    """Weights, engine and warm-up: (front end, weights, device, peaks)."""
+    """Weights, engine and warm-up: (front end, weights, the cell's
+    devices, peaks)."""
     import jax
 
     cfg, settings = cell.config, cell.settings
     compiles = CompileCounter()
     cache_dir = enable_compile_cache()
-    dev, peaks = device_info(cell, require_tpu)
-    log(f"bench: {cell.name} seed {seed} on {len(jax.devices())} x "
-        f"{dev.device_kind} ({dev.platform}); compile cache {cache_dir}")
-    w_dev = weights.make(cfg, seed)
+    devs, peaks = device_info(cell, require_tpu)
+    log(f"bench: {cell.name} seed {seed} on {len(devs)} x "
+        f"{devs[0].device_kind} ({devs[0].platform}); compile cache "
+        f"{cache_dir}")
+    mesh = cell_mesh(devs)
+    w_dev = weights.make(cfg, seed, mesh)
     jax.block_until_ready(w_dev)
     engine, Request, EngineStats = program.build(
-        cfg, cell.config_name, settings, w_dev, seed)
+        cfg, cell.config_name, settings, w_dev, seed, mesh)
     if engine_hook is not None:
         engine_hook(engine)
     drv = FrontEnd(engine, Request, EngineStats, compiles)
     t_warm = time.perf_counter()
     drv.serve_all(coverage_items(settings, cfg["vocab_size"], seed))
     log(f"bench: warm-up {time.perf_counter() - t_warm:.1f} s")
-    return drv, w_dev, dev, peaks
+    return drv, w_dev, devs, peaks
 
 
 def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
@@ -381,11 +423,9 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
     """One run of one cell.  Returns the result object; the caller prints
     it.  ``cell`` and ``engine_hook`` (applied to the built engine) let the
     tests run the harness at a small size with a broken engine."""
-    import jax
-
     cell = cell or spec.cell(workload)
     cfg, mix, settings = cell.config, cell.mix, cell.settings
-    drv, w_dev, dev, peaks = setup(cell, seed, require_tpu, engine_hook)
+    drv, w_dev, devs, peaks = setup(cell, seed, require_tpu, engine_hook)
 
     loop = mix["loop"]
     items = traffic.stream(mix, seed, cfg["vocab_size"],
@@ -394,15 +434,13 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
     trace_at = max(0.0, (seconds - TRACE_SECONDS) / 2) if trace_on else None
     w = drv.window(items, seconds, loop=loop,
                    clients=settings.get("clients", 0), trace_at=trace_at)
-    mem = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    device = device_record(devs)
     values, attempted, failed, counts = end_to_end(w, loop)
     values["setup_s"] = setup_s
     counts.update(compiles_in_window=w.compiles, **{
         f"engine_{k}": v for k, v in w.counters.items()})
     log("bench: samples " + json.dumps(counts))
 
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": int(mem)}
     result: dict = {}
     if trace_on:
         run_ctx, breakdown, sample = traced(w, cell, cfg, peaks, settings,
@@ -417,7 +455,7 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
             v = spec.metric_reader(m["name"])(run_ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-        device.update(busy_s=run_ctx.busy_s, window_s=run_ctx.window_s)
+        device = device_record(devs, run_ctx)
         result["breakdown"] = breakdown
     else:
         metrics = {}

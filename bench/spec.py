@@ -2,7 +2,24 @@
 names each workload's configuration and traffic mix; their files live in
 ``bench/configs/``, ``bench/mixes/`` and ``bench/cells/``, and each
 per-layer metric's reader in ``bench/metrics/``.  Adding a cell, a
-configuration, a mix or a metric means adding files only."""
+configuration, a mix or a metric means adding files only.
+
+A configuration names its ``architecture``; a new one adds two files:
+
+- ``bench/arch/<architecture>.py``, the benchmark's side of it:
+  ``model_config(cfg, name)`` (the program's config), ``params(model,
+  w)`` (the flat weights nested into the engine's tree, checked leaf for
+  leaf), ``shapes(cfg)`` (``{weight: (shape, initializer kind)}``),
+  ``specs(cfg, chips)`` (``{weight: PartitionSpec}`` on a ``model`` axis,
+  as the program's sharding rules place the nested tree), ``sites(cfg)``
+  (``{site: (k, n, gemms per layer, layers)}`` of the protected GEMMs)
+  and ``SPLIT`` (``{site: "column" | "row" | "vocab"}``);
+- ``bench/reference/<architecture>.py``, its plain float32 reference:
+  ``logits(w, cfg, tokens, rows, precision)``, importing nothing of the
+  program.
+
+A cell with ``chips`` > 1 serves over a ``(data=1, model=chips)`` mesh
+of the first ``chips`` devices; nothing else in its files says so."""
 
 from __future__ import annotations
 
@@ -76,6 +93,11 @@ def metric_reader(name: str):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def arch(architecture: str):
+    """The architecture module ``bench/arch/<architecture>.py``."""
+    return importlib.import_module(f"bench.arch.{architecture}")
 
 
 def reference(architecture: str):

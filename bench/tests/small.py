@@ -1,5 +1,6 @@
 """A cell of the benchmark cut to a size the CPU runs in seconds: the
-same mix shapes, configuration keys and harness, small widths."""
+same mix shapes, configuration keys and harness, small widths; with
+``chips`` > 1, served over a mesh of that many (virtual) devices."""
 
 from __future__ import annotations
 
@@ -8,13 +9,18 @@ import dataclasses
 from bench import spec
 
 
-def small_cell(name: str, limit: float = 0.05):
+def small_cell(name: str, limit: float = 0.05, chips: int = 1):
     base = spec.cell(name)
+    if chips > 1:
+        base = dataclasses.replace(base, name=f"{name}.tp{chips}",
+                                   chips=chips)
     qwen = base.config["qk_norm"]
+    # on a mesh, two query heads a device, the KV heads split as well
+    heads = 4 if chips == 1 else 2 * chips
+    kv = heads // 2 if qwen else heads
     cfg = dict(base.config, hidden_size=64, intermediate_size=128,
-               num_hidden_layers=2, num_attention_heads=4,
-               num_key_value_heads=2 if qwen else 4, head_dim=16,
-               vocab_size=512)
+               num_hidden_layers=2, num_attention_heads=heads,
+               num_key_value_heads=kv, head_dim=16, vocab_size=512)
     mix = dict(base.mix)
     if mix["loop"] == "open":
         mix.update(prompt_tokens=dict(mix["prompt_tokens"], median=24,

@@ -1,5 +1,7 @@
 """Operation and byte counts against hand-computed values."""
 
+import pytest
+
 from bench import spec, work
 
 
@@ -45,3 +47,62 @@ def test_window_work_takes_the_larger_bound():
     fl4, by4 = work.site_work(c, "lm_head", 6)
     assert least["lm_head"] == max(fl4 / peak, by4 / bw) + max(fl3 / peak,
                                                                by3 / bw)
+
+
+# recorded from the weights and sites before they moved into
+# bench/arch/dense_decoder.py: small widths of each configuration, every
+# key and the table order as they were
+DIGESTS = {
+    "stablelm-2-1.6b.chat":
+        "c0164464b72a994286bc442ecf43841da82d6104bb36acb7bb76f2e0b1ff5dfd",
+    "qwen3-14b-pp4.docs":
+        "80c356082080ce7595e3666d3e31c14a1ac38e73aa8053a56072c7a22bebc160",
+}
+SITES = {
+    "stablelm-2-1.6b.chat": {
+        "attn.q": (2048, 2048, 1, 24), "attn.k": (2048, 2048, 1, 24),
+        "attn.v": (2048, 2048, 1, 24), "attn.o": (2048, 2048, 1, 24),
+        "mlp.up": (2048, 5632, 2, 24), "mlp.down": (5632, 2048, 1, 24),
+        "lm_head": (2048, 100352, 1, 1)},
+    "qwen3-14b-pp4.docs": {
+        "attn.q": (5120, 5120, 1, 10), "attn.k": (5120, 1024, 1, 10),
+        "attn.v": (5120, 1024, 1, 10), "attn.o": (5120, 5120, 1, 10),
+        "mlp.up": (5120, 17408, 2, 10), "mlp.down": (17408, 5120, 1, 10),
+        "lm_head": (5120, 151936, 1, 1)},
+}
+
+
+@pytest.mark.parametrize("name", sorted(DIGESTS))
+def test_weights_and_sites_as_recorded(name):
+    import hashlib
+
+    import numpy as np
+
+    from bench import weights
+    from bench.tests.small import small_cell
+
+    cfg = small_cell(name).config
+    w = weights.make(cfg, 2**31 + 17)
+    h = hashlib.sha256()
+    for k in sorted(w):
+        h.update(k.encode())
+        h.update(np.asarray(w[k]).tobytes())
+    assert h.hexdigest() == DIGESTS[name]
+    assert work.sites(_cfg(name)) == SITES[name]
+    assert work.device_sites(_cfg(name), 1) == SITES[name]
+
+
+def test_device_sites_split_by_columns_rows_and_vocabulary():
+    c = _cfg("qwen3-14b-pp4.docs")
+    assert work.device_sites(c, 4) == {
+        "attn.q": (5120, 1280, 1, 10), "attn.k": (5120, 256, 1, 10),
+        "attn.v": (5120, 256, 1, 10), "attn.o": (1280, 5120, 1, 10),
+        "mlp.up": (5120, 4352, 2, 10), "mlp.down": (4352, 5120, 1, 10),
+        "lm_head": (5120, 37984, 1, 1)}
+    # the whole model's operations are split four ways
+    whole = sum(work.site_work(c, s, 8)[0] for s in work.sites(c))
+    shard = sum(work.site_work(c, s, 8, 4)[0] for s in work.sites(c))
+    assert 4 * shard == whole
+    # a width four does not divide stays whole on every device
+    odd = dict(c, num_key_value_heads=1, head_dim=127)
+    assert work.device_sites(odd, 4)["attn.k"] == (5120, 127, 1, 10)

@@ -21,6 +21,9 @@ ABFT_RE = re.compile(r"abft\[([^\]]*)\]\[([^\]]*)\]")
 MARK_RE = re.compile(r"(abft\[[^\]]*\]\[[^\]]*\]|flops\[[^\]]*\])")
 DIGITS_RE = re.compile(r"[.\d]+$")
 CONTAINER_RE = re.compile(r"\s(while|conditional|call)\(")
+COLLECTIVES = r"(?:all-reduce-scatter|all-reduce|all-gather|" \
+              r"reduce-scatter|all-to-all|collective-permute)(?:-start|-done)?"
+COLLECTIVE_RE = re.compile(rf"(?:^%?|\s)({COLLECTIVES})(?:[.(\s]|$)")
 MAIN_DOT_RE = re.compile(r"/(dot_general|matmul)")
 HOST_PREFIX = "bench."
 
@@ -29,6 +32,9 @@ PROGRAM_RE = re.compile(r"\((\d+)\)\s*$")
 INSTR_RE = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+) = .*?metadata=\{op_name="'
                       r'([^"]*)"', re.M)
 NAME_RE = re.compile(r"^%?([^\s=]+)")
+COLLECTIVE_INSTR_RE = re.compile(
+    rf"^\s*(?:ROOT\s+)?%([^\s=]+) = [^\n]*?(?:\s({COLLECTIVES})\(|"
+    rf"\sfusion\([^\n]*?calls=%({COLLECTIVES})[.\w-]*)", re.M)
 
 
 def op_names(hlo_text: str) -> dict:
@@ -36,11 +42,23 @@ def op_names(hlo_text: str) -> dict:
     return {m.group(1): m.group(2) for m in INSTR_RE.finditer(hlo_text)}
 
 
+def collective_ops(hlo_text: str) -> dict:
+    """{instruction name: opcode} of the exchanges between chips in a
+    compiled module's text.  The name alone does not tell: a ``psum``
+    under ``shard_map`` is an ``all-reduce`` named ``psum.<n>``, and the
+    TPU compiler fuses a reduce-scatter into a ``fusion.<n>`` that calls
+    an ``all-reduce-scatter`` computation (opcode given as that name)."""
+    return {m.group(1): m.group(2) or m.group(3)
+            for m in COLLECTIVE_INSTR_RE.finditer(hlo_text)}
+
+
 def load(log_dir: str) -> dict:
     """{"window_ns", "devices": {plane: [op, ...]}, "host": [span, ...],
     "sample": [...]} from the one xplane file under ``log_dir``.  An op's
     scope is the ``op_name`` that its compiled module's metadata gives
-    the instruction, found through the module the op ran in."""
+    the instruction, found through the module the op ran in; a
+    collective's name gets its opcode appended (``psum.3 all-reduce(``),
+    as the module's text gives it."""
     from bench import xplane
 
     paths = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
@@ -63,11 +81,11 @@ def load(log_dir: str) -> dict:
         raise RuntimeError("trace has no profile start and stop time")
     names_of: dict = {}
 
-    def scope_of(program, instr):
+    def module(program):
         if program not in names_of:
-            names_of[program] = op_names(xplane.hlo_text(hlo[program])) \
-                if program in hlo else {}
-        return names_of[program].get(instr, "")
+            text = xplane.hlo_text(hlo[program]) if program in hlo else ""
+            names_of[program] = (op_names(text), collective_ops(text))
+        return names_of[program]
 
     devices, host, sample = {}, [], []
     for pl in planes:
@@ -87,7 +105,12 @@ def load(log_dir: str) -> dict:
                 program = modules[k][2] if k < len(modules) and \
                     modules[k][0] <= s else None
                 m = NAME_RE.match(name)
-                scope = scope_of(program, m.group(1)) if m else ""
+                scope = ""
+                if m:
+                    scopes, colls = module(program)
+                    scope = scopes.get(m.group(1), "")
+                    if m.group(1) in colls and not is_collective(name):
+                        name = f"{name} {colls[m.group(1)]}("
                 if len(sample) < 60:
                     sample.append([name[:160], program, scope])
                 ops.append((s, e, name, scope))
@@ -147,11 +170,35 @@ def idle_gaps(ops, window_ns: float) -> list:
     return [(s, e) for s, e in gaps if e > s]
 
 
+def is_collective(name: str) -> bool:
+    """An exchange between chips, by the opcode in its name (``load``
+    appends it): an ``all-reduce``, ``all-gather``, ``reduce-scatter``
+    (or the TPU's fused ``all-reduce-scatter``), ``all-to-all`` or
+    ``collective-permute``, or the ``-start`` or ``-done`` half of an
+    asynchronous one."""
+    return bool(COLLECTIVE_RE.search(name))
+
+
+def collective_ns(ops, window_ns: float) -> float | None:
+    """Time in [0, window) in which a collective runs on the device and
+    no other op does (control-flow containers, which span their bodies,
+    aside), or None where the device ran no collective."""
+    coll = [o for o in ops if is_collective(o[2])]
+    if not coll:
+        return None
+    rest = [o for o in ops if not is_collective(o[2])
+            and not is_container(o[2])]
+    return busy_ns(coll + rest, window_ns) - busy_ns(rest, window_ns)
+
+
 def site_seconds(ops, scheme: str | None = None) -> dict:
     """{site: seconds of device ops under its abft scope}, optionally of
-    one scheme only."""
+    one scheme only; collectives are left out (``collective_ns`` counts
+    them)."""
     out = collections.defaultdict(float)
-    for s, e, _, scope in ops:
+    for s, e, name, scope in ops:
+        if is_collective(name):
+            continue
         m = ABFT_RE.search(scope) if scope else None
         if m and (scheme is None or m.group(1) == scheme):
             out[m.group(2)] += (e - s) / 1e9

@@ -1,9 +1,12 @@
 """Random weights from the seed, made by the benchmark on the device in
 one jitted call, in bfloat16 (the type they are served in).
 
-The layout is the benchmark's own: a flat dict of arrays stacked over
-layers.  ``program.params`` nests the same arrays (no copy) into the
-tree the serving engine takes; the plain reference reads this dict.
+The layout is the architecture's (``bench/arch/<architecture>.py``
+``shapes``): a flat dict of arrays stacked over layers, which
+``program.params`` nests (no copy) into the tree the serving engine
+takes and the plain reference reads as it is.  On a mesh each device
+makes only its own shard (``specs``); with JAX's partitionable threefry
+the values are those of the one-device call.
 
 Scales keep every layer's output of order one, so the logits spread
 over a few units and the greedy token is not a coin toss between
@@ -17,45 +20,14 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+
+from bench import spec
 
 BF16 = jnp.bfloat16
 
 
-def shapes(cfg: dict) -> dict:
-    """{name: (shape, kind)}; kind picks the initializer."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    hd = cfg["head_dim"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    L, V = cfg["num_hidden_layers"], cfg["vocab_size"]
-    ln = cfg["norm"] == "layernorm"
-    out = {
-        "embed": ((V, d), "embed"),
-        "final_norm_w": ((d,), "gain"),
-        "lm_head": ((d, V), "gemm"),
-        "attn_norm_w": ((L, d), "gain"),
-        "mlp_norm_w": ((L, d), "gain"),
-        "wq": ((L, d, q), "gemm"),
-        "wk": ((L, d, kv), "gemm"),
-        "wv": ((L, d, kv), "gemm"),
-        "wo": ((L, q, d), "gemm"),
-        "w_gate": ((L, d, f), "gemm"),
-        "w_up": ((L, d, f), "gemm"),
-        "w_down": ((L, f, d), "gemm"),
-    }
-    if ln:
-        out.update(final_norm_b=((d,), "bias"), attn_norm_b=((L, d), "bias"),
-                   mlp_norm_b=((L, d), "bias"))
-    if cfg.get("use_qkv_bias"):
-        out.update(bq=((L, q), "bias"), bk=((L, kv), "bias"),
-                   bv=((L, kv), "bias"))
-    if cfg.get("qk_norm"):
-        out.update(q_norm_w=((L, hd), "gain"), k_norm_w=((L, hd), "gain"))
-    return out
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _make(key, table):
+def _body(key, table):
     out = {}
     keys = jax.random.split(key, len(table))
     for k, (name, shape, kind) in zip(keys, table):
@@ -70,9 +42,19 @@ def _make(key, table):
     return out
 
 
-def make(cfg: dict, seed: int) -> dict:
-    """The weights of ``cfg`` for ``seed``, on the default device."""
-    table = tuple((n, s, k) for n, (s, k) in sorted(shapes(cfg).items()))
+_make = jax.jit(_body, static_argnums=(1,))
+
+
+def make(cfg: dict, seed: int, mesh=None) -> dict:
+    """The weights of ``cfg`` for ``seed``: on the default device, or
+    sharded over ``mesh``'s ``model`` axis."""
+    arch = spec.arch(cfg["architecture"])
+    table = tuple((n, s, k) for n, (s, k) in sorted(arch.shapes(cfg).items()))
     key = jax.random.key(seed % (1 << 32))
     key = jax.random.fold_in(key, (seed >> 32) % (1 << 31))
-    return _make(key, table)
+    if mesh is None:
+        return _make(key, table)
+    specs = arch.specs(cfg, mesh.shape["model"])
+    out = {n: NamedSharding(mesh, specs[n]) for n, _, _ in table}
+    return jax.jit(functools.partial(_body, table=table),
+                   out_shardings=out)(key)
